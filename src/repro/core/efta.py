@@ -42,30 +42,6 @@ from repro.core.fault import FaultSpec, Site, inject
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-# optimization_barrier defeats CSE so the shadow (DMR) accumulators are
-# genuinely redundant computations on real hardware — but this jax version
-# ships the primitive without batching or differentiation rules, which breaks
-# vmap (the serve engine's batched decode) and jax.grad (training). Both
-# rules are mathematically trivial: the barrier is the identity function, so
-# batching keeps the batch axis and the JVP passes tangents through.
-from jax.interpreters import batching as _batching  # noqa: E402
-
-if jax.lax.optimization_barrier_p not in _batching.primitive_batchers:
-    def _ob_batch(args, dims):
-        return jax.lax.optimization_barrier_p.bind(*args), dims
-    _batching.primitive_batchers[jax.lax.optimization_barrier_p] = _ob_batch
-
-
-@jax.custom_jvp
-def _shadow_barrier(x):
-    return jax.lax.optimization_barrier(x)
-
-
-@_shadow_barrier.defjvp
-def _shadow_barrier_jvp(primals, tangents):
-    return _shadow_barrier(primals[0]), tangents[0]
-
-
 @dataclasses.dataclass(frozen=True)
 class EFTAConfig:
     """Fault-tolerance + tiling configuration for EFTA."""
@@ -333,7 +309,7 @@ def efta_attention(
             # Recompute-compare on the (cheap) rowmax recurrence: protects
             # against fp overflow from an understated max, which the paper's
             # analytic-cancellation argument (Case 1) does not cover.
-            m_chk = jnp.maximum(_shadow_barrier(m_prev), blockmax)
+            m_chk = jnp.maximum(jax.lax.optimization_barrier(m_prev), blockmax)
             bad_m = m_new != m_chk
             rep = FTReport(
                 rep.detected.at[2].add(bad_m.sum(dtype=jnp.int32)),
@@ -409,7 +385,7 @@ def efta_attention(
         l_new = inject(l_new, fault, Site.ROWSUM, blk_idx)
         if ft and cfg.shadow_rowsum:
             # Redundant accumulation (barrier defeats CSE on real hardware).
-            row_sh = jnp.sum(_shadow_barrier(p), axis=-1)
+            row_sh = jnp.sum(jax.lax.optimization_barrier(p), axis=-1)
             lsh_new = alpha * lsh_prev + row_sh
         else:
             lsh_new = lsh_prev

@@ -52,9 +52,12 @@ injected here: they strike the resident pool between steps
 (``PagedServeEngine.inject_kv_fault``) and this kernel's job is to catch
 them.
 
-Validated in interpret mode on CPU; lowers for TPU via Mosaic (on real TPUs
-pick ``head_dim``/``block_size`` multiples of the (8, 128) f32 tile and a
-``group * C`` row count that is a multiple of 8).
+Validated in interpret mode on CPU. Compiles through Mosaic for a TPU v5e
+in bf16 at head_dim 64, block 16, chunk 1 and 32, and with 4 query heads per
+KV head (``tests/test_tpu_compile.py``, against a described chip), and
+serves gpt2 at full width on one v5e chip (``chip_smoke.py``). The report
+tile is one (8, 128k) int32 tile per (request, kv-head): row 0 holds the six
+detection counters, row 1 the per-table-slot bad plane.
 """
 from __future__ import annotations
 
@@ -69,8 +72,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import checksum as cks
 from repro.core.efta import EFTAConfig, MASK_VALUE
 from repro.core.fault import Site
-from repro.kernels.efta_attention import (_CompilerParams, _correct_strided,
-                                          _flip, _fold_prod, _fold_slices)
+from repro.kernels.efta_attention import (REPORT_TILE, _correct_strided,
+                                          _flip, _fold_prod, _fold_slices,
+                                          _report_tile, _shadow_rowmax,
+                                          _shadow_rowsum, resolve_interpret)
 
 # fault descriptor layout (int32[8]):
 # [site, table_block, batch, kv_head, tile_row, col, bit, enabled]
@@ -103,7 +108,7 @@ def _paged_kernel(
     # inputs
     q_ref, k_ref, v_ref, kc1_ref, kc2_ref, vc1_ref, vc2_ref,
     # outputs
-    o_ref, rep_ref, bad_ref,
+    o_ref, rep_ref,
     # scratch
     m_scr, l_scr, lsh_scr, r_scr, acc_scr, oc1_scr, oc2_scr, det_scr,
     vmax_scr,
@@ -148,7 +153,7 @@ def _paged_kernel(
         for i in range(6):
             det_scr[i] = 0
         vmax_scr[0] = 0.0
-        bad_ref[...] = jnp.zeros_like(bad_ref)
+        rep_ref[...] = jnp.zeros_like(rep_ref)
 
     # Ragged / causal skip: blocks entirely past every chunk row's valid
     # prefix (or entirely outside every row's sliding window — the earliest
@@ -179,10 +184,11 @@ def _paged_kernel(
                 fv, cks.Checksums(vc1_ref[...], vc2_ref[...]), threshold=kv_thr)
             flag = (bad_k | bad_v) & real
             det_scr[5] += flag.astype(jnp.int32)
-            onehot = jax.lax.broadcasted_iota(
-                jnp.int32, bad_ref.shape, 1) == j
-            bad_ref[...] = jnp.maximum(
-                bad_ref[...], (onehot & flag).astype(jnp.int32))
+            # row 1 of the report tile is the bad plane: lane j = table slot
+            hit = ((jax.lax.broadcasted_iota(jnp.int32, rep_ref.shape, 0) == 1)
+                   & (jax.lax.broadcasted_iota(jnp.int32, rep_ref.shape, 1)
+                      == j) & flag)
+            rep_ref[...] = jnp.where(hit, 1, rep_ref[...])
 
             # running max|V|: the convex-combination bound for finalize NVR
             vmax_scr[0] = jnp.maximum(
@@ -235,7 +241,7 @@ def _paged_kernel(
                       row=fault_ref[P_ROW], col=jnp.int32(0),
                       bit=fault_ref[P_BIT])
         if ft and shadow_rowmax:
-            m_chk = jnp.maximum(jax.lax.optimization_barrier(m_prev), blockmax)
+            m_chk = jnp.maximum(m_prev, _shadow_rowmax(s_m, s_kv))
             bad_m = m_new != m_chk
             det_scr[2] += bad_m.sum(dtype=jnp.int32)
             if correct:
@@ -263,12 +269,10 @@ def _paged_kernel(
             det_scr[1] += bad_e.sum(dtype=jnp.int32)
             if correct:
                 recomputed = jnp.exp(jnp.minimum(s - m_sub, cap))
-                for l in range(g_kv):
-                    seg = jnp.where(
-                        bad_e, recomputed[:, l * s_kv:(l + 1) * s_kv],
-                        p_raw[:, l * s_kv:(l + 1) * s_kv])
-                    p_raw = jax.lax.dynamic_update_slice(
-                        p_raw, seg, (0, l * s_kv))
+                p_raw = jnp.concatenate(
+                    [jnp.where(bad_e, recomputed[:, l * s_kv:(l + 1) * s_kv],
+                               p_raw[:, l * s_kv:(l + 1) * s_kv])
+                     for l in range(g_kv)], axis=-1)
         if ft and shadow_rowmax and correct:
             # exact recompute backstop (see efta_attention)
             recheck = jnp.exp(jnp.minimum(s - m_sub, cap))
@@ -285,9 +289,7 @@ def _paged_kernel(
                       bit=fault_ref[P_BIT])
         l_scr[...] = l_new
         if ft and shadow_rowsum:
-            p_sh = jax.lax.optimization_barrier(p)
-            lsh_scr[...] = alpha * lsh_scr[...] + jnp.sum(p_sh, axis=1,
-                                                          keepdims=True)
+            lsh_scr[...] = alpha * lsh_scr[...] + _shadow_rowsum(p, s_kv)
         blk_alive = blockmax > MASK_VALUE / 2
         r_scr[...] = alpha * r_scr[...] + jnp.where(
             blk_alive, jnp.exp(blockmax - m_sub), 0.0)
@@ -364,8 +366,8 @@ def _paged_kernel(
             if correct:
                 o = _correct_strided(o, d1, d2, bad, s_out)
         o_ref[...] = o.astype(o_ref.dtype)
-        for i in range(6):
-            rep_ref[i] = det_scr[i]
+        rep_ref[...] = _report_tile(rep_ref[...],
+                                    [det_scr[i] for i in range(6)])
 
 
 def efta_paged_attention_pallas(
@@ -383,7 +385,7 @@ def efta_paged_attention_pallas(
     window=None,
     sm_scale: Optional[float] = None,
     fault: Optional[jax.Array] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> PagedReport:
     """Fused batched ragged paged attention with in-loop verification.
 
@@ -404,8 +406,9 @@ def efta_paged_attention_pallas(
 
     ``window``: optional sliding-window size — python int or traced int32
     scalar (per-layer global/local selection). ``fault``: optional int32[8]
-    descriptor (see module docstring). Returns a :class:`PagedReport` whose
-    ``out`` matches ``q``'s shape.
+    descriptor (see module docstring). ``interpret`` as in
+    :func:`repro.kernels.efta_attention.efta_attention_pallas`. Returns a
+    :class:`PagedReport` whose ``out`` matches ``q``'s shape.
     """
     squeeze = q.ndim == 3
     if squeeze:
@@ -445,6 +448,9 @@ def efta_paged_attention_pallas(
         return (bt[bi, j], hi, 0, 0)
 
     rows = grp * chunk
+    # report tile: row 0 = the six detection counters, row 1 = the bad plane
+    # (lane j = table slot j), padded to whole (8, 128) tiles
+    rep_tile = (REPORT_TILE[0], -(-mb // REPORT_TILE[1]) * REPORT_TILE[1])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(b, hkv, mb),
@@ -461,8 +467,7 @@ def efta_paged_attention_pallas(
         out_specs=[
             pl.BlockSpec((None, None, rows, d),
                          lambda bi, hi, j, *_: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, 6), lambda bi, hi, j, *_: (bi, hi, 0)),
-            pl.BlockSpec((None, None, 1, mb),
+            pl.BlockSpec((None, None) + rep_tile,
                          lambda bi, hi, j, *_: (bi, hi, 0, 0)),
         ],
         scratch_shapes=[
@@ -478,17 +483,16 @@ def efta_paged_attention_pallas(
         ],
     )
 
-    out, rep, bad = pl.pallas_call(
+    out, rep = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hkv, 6), jnp.int32),
-            jax.ShapeDtypeStruct((b, hkv, 1, mb), jnp.int32),
+            jax.ShapeDtypeStruct((b, hkv) + rep_tile, jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(fault, jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(kv_lens, jnp.int32), jnp.asarray(q_lens, jnp.int32), win,
       qr, k_pool, v_pool, k_checks.c1, k_checks.c2, v_checks.c1, v_checks.c2)
@@ -496,8 +500,8 @@ def efta_paged_attention_pallas(
     out = out.reshape(b, h, chunk, d)
     return PagedReport(
         out=out[:, :, 0, :] if squeeze else out,
-        detected=rep.sum(axis=1),
-        bad_blocks=jnp.any(bad > 0, axis=(1, 2)))
+        detected=rep[:, :, 0, :6].sum(axis=1),
+        bad_blocks=jnp.any(rep[:, :, 1, :mb] > 0, axis=1))
 
 
 def paged_fault_descriptor(spec, grp: int,

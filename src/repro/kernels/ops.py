@@ -1,14 +1,13 @@
 """Jit'd dispatch for attention implementations.
 
 ``attention(...)`` routes between:
-  * ``efta_pallas`` — the fused Pallas TPU kernel (interpret=True on CPU)
+  * ``efta_pallas`` — the fused Pallas TPU kernel (interpreted off-TPU)
   * ``efta``        — pure-JAX EFTA (jit/pjit/differentiable; used at scale)
   * ``flash``       — pure-JAX flash attention, fault tolerance off
   * ``reference``   — naive O(n²) softmax attention
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -32,7 +31,6 @@ def attention(
     sm_scale: Optional[float] = None,
     fault=None,
     kv_positions=None,
-    interpret: bool = True,
 ):
     """Unified attention entry point. Returns (out, FTReport)."""
     cfg = cfg or EFTAConfig()
@@ -61,7 +59,7 @@ def attention(
         out, det = efta_attention_pallas(
             q, k, v, cfg=cfg, causal=causal, window=window,
             kv_len=None if kv_len is None else int(kv_len),
-            sm_scale=sm_scale, fault=fault, interpret=interpret)
+            sm_scale=sm_scale, fault=fault)
         return out, FTReport(det, det if cfg.mode == "correct" else det * 0,
                              jnp.zeros((3,), jnp.float32))
     raise ValueError(f"unknown attention impl {impl!r}; one of {IMPLS}")
@@ -111,11 +109,3 @@ def gather_block_kv(pool: jax.Array,
     """
     raw = pool[:, block_table]
     return raw, merge_block_axes(raw)
-
-
-@functools.partial(jax.jit, static_argnames=("impl", "cfg", "causal", "window",
-                                             "sm_scale", "interpret"))
-def attention_jit(q, k, v, *, impl="efta", cfg=None, causal=False, window=None,
-                  sm_scale=None, interpret=True):
-    return attention(q, k, v, impl=impl, cfg=cfg, causal=causal, window=window,
-                     sm_scale=sm_scale, interpret=interpret)

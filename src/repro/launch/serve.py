@@ -6,12 +6,20 @@ CPU-scale demos:
       --requests 8 --slots 4 --max-prompt 24 --gen 16 --inject-faults 3
   PYTHONPATH=src python -m repro.launch.serve --arch gpt2-smoke --paged \
       --shared-prefix 16 --kv-flips 2
+
+Full width on a TPU (the path ``chip_smoke.py`` drives):
+  PYTHONPATH=src python -m repro.launch.serve --arch gpt2 --chunked-prefill \
+      --requests 8 --slots 8 --min-prompt 32 --max-prompt 512 --gen 32
+
+:func:`serve` runs the whole command for a given ``argv`` and returns the
+engine, the prompts and the outputs, so a caller can check what was served.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import time
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import numpy as np
@@ -22,6 +30,7 @@ from repro.models import build_model
 from repro.serve import (PagedServeEngine, SamplingParams, ServeEngine,
                          batch_faults)
 from repro.utils import get_logger
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _static_batch_serve(cfg, model, params, rng, args, log):
@@ -48,11 +57,20 @@ def _static_batch_serve(cfg, model, params, rng, args, log):
     print(np.asarray(out))
 
 
-def main():
+class ServeRun(NamedTuple):
+    """What one :func:`serve` call built and produced."""
+
+    engine: ServeEngine
+    prompts: List[np.ndarray]      # in submission order (request id order)
+    outputs: Dict[int, np.ndarray]  # request id -> generated tokens
+
+
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt2-smoke")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
+    ap.add_argument("--min-prompt", type=int, default=2)
     ap.add_argument("--max-prompt", type=int, default=24)
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--cache-len", type=int, default=0,
@@ -126,8 +144,9 @@ def main():
     ap.add_argument("--ft-mode", default=None,
                     help="override the config's EFTA mode (off/detect/correct)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
-    log = get_logger("serve")
+    args = ap.parse_args(argv)
+    if not 1 <= args.min_prompt <= args.max_prompt:
+        ap.error("need 1 <= --min-prompt <= --max-prompt")
     if args.chunked_prefill:
         if args.kernel == "gather":
             ap.error("--chunked-prefill is the fused unified step; it "
@@ -145,21 +164,11 @@ def main():
         ap.error("--kernel/--kv-verify/--chunk-size/--chunk-budget/"
                  "--scrub-interval configure the paged engine; add --paged")
     args.kernel = args.kernel or "gather"
+    return args
 
-    cfg = get_config(args.arch)
-    if args.ft_mode:
-        cfg = dataclasses.replace(
-            cfg, ft=dataclasses.replace(cfg.ft, mode=args.ft_mode))
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
-    rng = np.random.default_rng(args.seed)
 
-    if cfg.family not in ("dense", "moe"):
-        # frontend / ssm / encdec families predate the continuous-batching
-        # engine: serve them through the static-batch greedy loop
-        _static_batch_serve(cfg, model, params, rng, args, log)
-        return
-
+def build_engine(args: argparse.Namespace, model, params) -> ServeEngine:
+    """The continuous-batching engine ``args`` asks for (dense / MoE)."""
     if args.paged:
         draft_model = draft_params = None
         if args.speculate == "draft":
@@ -188,15 +197,49 @@ def main():
     else:
         eng = ServeEngine(model, params, n_slots=args.slots,
                           cache_len=args.cache_len or None)
+    return eng
+
+
+def submit_requests(eng: ServeEngine, args: argparse.Namespace,
+                    rng: np.random.Generator) -> List[np.ndarray]:
+    """Submit ``--requests`` seeded prompts (an optional shared prefix plus
+    ``--min-prompt``..``--max-prompt`` random tokens); returns them."""
     sampling = SamplingParams(temperature=args.temperature,
                               top_k=args.top_k, seed=args.seed)
-    shared = rng.integers(0, cfg.vocab_size,
-                          (args.shared_prefix,)).astype(np.int32)
+    vocab = eng.model.cfg.vocab_size
+    shared = rng.integers(0, vocab, (args.shared_prefix,)).astype(np.int32)
+    prompts = []
     for _ in range(args.requests):
-        t = int(rng.integers(2, args.max_prompt + 1))
+        t = int(rng.integers(args.min_prompt, args.max_prompt + 1))
         prompt = np.concatenate(
-            [shared, rng.integers(0, cfg.vocab_size, (t,)).astype(np.int32)])
+            [shared, rng.integers(0, vocab, (t,)).astype(np.int32)])
+        prompts.append(prompt)
         eng.submit(prompt, max_new_tokens=args.gen, sampling=sampling)
+    return prompts
+
+
+def serve(argv: Optional[Sequence[str]] = None) -> Optional[ServeRun]:
+    """Build the model and engine for ``argv``, submit the seeded requests,
+    drive them to completion and log the telemetry. Families without a
+    continuous-batching engine run the static-batch loop and return None."""
+    args = parse_args(argv)
+    log = get_logger("serve")
+    cfg = get_config(args.arch)
+    if args.ft_mode:
+        cfg = dataclasses.replace(
+            cfg, ft=dataclasses.replace(cfg.ft, mode=args.ft_mode))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(args.seed))
+    rng = np.random.default_rng(args.seed)
+
+    if cfg.family not in ("dense", "moe"):
+        # frontend / ssm / encdec families predate the continuous-batching
+        # engine: serve them through the static-batch greedy loop
+        _static_batch_serve(cfg, model, params, rng, args, log)
+        return None
+
+    eng = build_engine(args, model, params)
+    prompts = submit_requests(eng, args, rng)
 
     faults_by_step = {}
     n_faults = min(args.inject_faults, args.gen)
@@ -214,6 +257,9 @@ def main():
     if args.paged and args.kv_flips:
         # drive manually so resident-state SEUs strike *between* steps
         outs, i, flips_left = {}, 0, args.kv_flips
+        # an exponent bit of the pool's dtype (bits 24..30 of f32, 8..14 of
+        # bf16) so every flip is a real, checksum-visible corruption
+        kv_bits = 8 * eng.pool.state.k.dtype.itemsize
         while eng.scheduler.has_work:
             live = [r for r in eng.scheduler.active_rows()
                     if not r.is_done() and eng._pos[r.slot] > 0]
@@ -226,7 +272,7 @@ def main():
                     head=int(rng.integers(0, cfg.attn.num_kv_heads)),
                     row=int(rng.integers(0, args.block_size)),
                     col=int(rng.integers(0, cfg.attn.head_dim)),
-                    bit=int(rng.integers(24, 31)),
+                    bit=int(rng.integers(kv_bits - 8, kv_bits - 1)),
                     into="k" if rng.integers(0, 2) else "v")
                 flips_left -= 1
             eng.step(faults=faults_by_step.get(i))
@@ -265,7 +311,14 @@ def main():
         log.info("request %d: %d tokens, detected=%d corrected=%d retries=%d",
                  rid, len(outs[rid]), st.total_detected if st else 0,
                  st.total_corrected if st else 0, st.retries if st else 0)
-    print({rid: outs[rid].tolist() for rid in sorted(outs)})
+    return ServeRun(eng, prompts, outs)
+
+
+def main():
+    enable_compile_cache()
+    run = serve()
+    if run is not None:
+        print({rid: run.outputs[rid].tolist() for rid in sorted(run.outputs)})
 
 
 if __name__ == "__main__":

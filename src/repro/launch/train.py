@@ -6,8 +6,7 @@ CPU-scale demo:
       --steps 60 --batch 8 --seq 64 --ckpt-dir /tmp/ckpt --ckpt-every 20
 
 Production notes: on a pod this runs under the 16x16 / 2x16x16 mesh from
-launch/mesh.py (pass --mesh pod|multipod); XLA's latency-hiding scheduler is
-enabled for compute/comm overlap via --xla-lhs.
+launch/mesh.py (pass --mesh pod|multipod).
 """
 from __future__ import annotations
 
@@ -28,9 +27,7 @@ from repro.models import build_model
 from repro.optim import AdamW, warmup_cosine
 from repro.train import TrainState, init_state, make_train_step
 from repro.utils import get_logger
-
-LHS_FLAGS = ("--xla_tpu_enable_latency_hiding_scheduler=true "
-             "--xla_tpu_megacore_fusion_allow_ags=true")
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -50,6 +47,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     log = get_logger("train")
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     model = build_model(cfg)

@@ -89,7 +89,7 @@ def init_cache(batch: int, a: AttnCfg, *, cache_len: int, dtype,
 
 
 def _paged_chunk(q, k, v, cache: PagedKVCache, *, cfg: EFTAConfig, window,
-                 sm_scale, fault, interpret: bool):
+                 sm_scale, fault):
     """One unified batched multi-token step against the paged block pool.
 
     ``q``/``k``/``v``: this step's projected (+RoPE'd) (B, H|Hkv, C, hd)
@@ -165,7 +165,7 @@ def _paged_chunk(q, k, v, cache: PagedKVCache, *, cfg: EFTAConfig, window,
         q, new_k, new_v,
         cks.Checksums(kc1, kc2), cks.Checksums(vc1, vc2),
         bt, pos + q_len, q_len, cfg=cfg, check_threshold=thr, window=window,
-        sm_scale=sm_scale, fault=fault, interpret=interpret)
+        sm_scale=sm_scale, fault=fault)
 
     tail_plane = (jnp.arange(mb, dtype=jnp.int32)[None, :] == j0[:, None]
                   ) & tail_bad[:, None]
@@ -282,7 +282,6 @@ def attn_apply(
     cross: bool = False,
     fault=None,
     mesh=None,
-    interpret: bool = True,
 ) -> tuple[jax.Array, FTReport, Optional[KVCache]]:
     b, s, _ = x.shape
     hd, h, hkv = acfg.head_dim, acfg.num_heads, acfg.num_kv_heads
@@ -345,7 +344,7 @@ def attn_apply(
         out, rep = attention_op(
             q, _tp_heads(_expand_kv(k)), _tp_heads(_expand_kv(v)),
             impl=ft.attn_impl, cfg=cfg, causal=False,
-            sm_scale=acfg.softmax_scale, fault=fault, interpret=interpret)
+            sm_scale=acfg.softmax_scale, fault=fault)
         new_cache = None
         if cache is not None and mode == "prefill":
             new_cache = cache._replace(ck=k, cv=v)
@@ -370,7 +369,7 @@ def attn_apply(
                 "extend step; training prefill has no paged cache")
         out, rep, new_cache = _paged_chunk(
             q, k, v, cache, cfg=cfg, window=window,
-            sm_scale=acfg.softmax_scale, fault=fault, interpret=interpret)
+            sm_scale=acfg.softmax_scale, fault=fault)
         y = matmul(_merge_heads(out), params["wo"], ff_abft=ft.ff_abft)
         return y, rep, new_cache
 
@@ -380,8 +379,7 @@ def attn_apply(
         out, rep = attention_op(
             q, _tp_heads(_expand_kv(k)), _tp_heads(_expand_kv(v)),
             impl=ft.attn_impl, cfg=cfg, causal=acfg.causal,
-            window=window, sm_scale=acfg.softmax_scale, fault=fault,
-            interpret=interpret)
+            window=window, sm_scale=acfg.softmax_scale, fault=fault)
     else:
         cache_len = cache.k.shape[2]
         slots = positions % cache_len
@@ -394,8 +392,7 @@ def attn_apply(
             out, rep = attention_op(
                 q, _tp_heads(_expand_kv(k)), _tp_heads(_expand_kv(v)),
                 impl=ft.attn_impl, cfg=cfg, causal=acfg.causal,
-                window=window, sm_scale=acfg.softmax_scale, fault=fault,
-                interpret=interpret)
+                window=window, sm_scale=acfg.softmax_scale, fault=fault)
         else:
             # Decode: attend over the valid region of the (ring) cache.
             # Each slot's absolute position is reconstructed so causal and
@@ -409,6 +406,6 @@ def attn_apply(
                 else ft.attn_impl,
                 cfg=cfg, causal=True, window=window,
                 q_offset=positions[0], kv_positions=kv_positions,
-                sm_scale=acfg.softmax_scale, fault=fault, interpret=interpret)
+                sm_scale=acfg.softmax_scale, fault=fault)
     y = matmul(_merge_heads(out), params["wo"], ff_abft=ft.ff_abft)
     return y, rep, new_cache

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MoECfg
-from repro.utils.compat import shard_map
 from repro.models.layers import ACTS, dense_init
 
 
@@ -145,7 +144,7 @@ def moe_apply(params, x, m: MoECfg, *, act: str = "silu",
         return y, aux
 
     wspec = P(ep_axis, fsdp, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(), wspec, wspec, wspec, P(dp if dp else None)),
         out_specs=(P(dp if dp else None), P()),
@@ -177,7 +176,7 @@ def _moe_inference_ep(params, x2, m: MoECfg, *, mesh, act, dp_axes, shape):
 
     wspec_in = P("data", None, "model")   # wg, wu: (E@data, d, ff@model)
     wspec_out = P("data", "model", None)  # wd:     (E@data, ff@model, d)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(), wspec_in, wspec_in, wspec_out, P()),
         out_specs=(P(), P()),

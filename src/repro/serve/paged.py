@@ -1116,13 +1116,9 @@ class PagedServeEngine(ServeEngine):
             q_lens[slot] = n_fill
             pos_vec = self._pos.copy()
             pos_vec[slot] = start
-            _, _, _, _, new_state = self._step_fused(
-                self.params, jnp.asarray(tokens), self.pool.state,
-                jnp.asarray(self._bt), jnp.asarray(pos_vec),
-                jnp.asarray(q_lens), self._no_faults,
-                jnp.asarray(self._temps), jnp.asarray(self._topks),
-                jnp.asarray(self._seeds), jnp.asarray(self._rids),
-                jnp.asarray(self._counters))
+            _, _, _, _, new_state = self._step_fused(*self._fused_args(
+                jnp.asarray(tokens), pos_vec, jnp.asarray(q_lens),
+                self._no_faults))
             self.pool.state = new_state
             self.pool.blocks.note_write(req.block_ids[j])
             self.paged_stats.kv_repaired_blocks += 1
@@ -1298,6 +1294,27 @@ class PagedServeEngine(ServeEngine):
             return self._step_unified(faults)
         return self._step_gather(faults)
 
+    def lower_step(self, chunk: int):
+        """Lower the fused unified step at chunk width ``chunk`` against the
+        live pool, with the argument types :meth:`_step_unified` passes:
+        the program the compiler is given (its ``as_text()`` shows whether
+        the paged kernel is compiled, as a ``tpu_custom_call``)."""
+        if self.kernel != "fused":
+            raise ValueError("lower_step inspects the fused unified step")
+        ns = self.n_slots
+        return self._step_fused.lower(*self._fused_args(
+            jnp.zeros((ns, chunk), jnp.int32), self._pos,
+            jnp.zeros((ns,), jnp.int32), self._no_faults))
+
+    def _fused_args(self, tokens, pos, q_lens, faults):
+        """Arguments of ``_step_fused``: this step's token chunk, per-slot
+        positions, chunk lengths and fault plan, plus the live pool, block
+        tables and sampling state."""
+        return (self.params, tokens, self.pool.state, jnp.asarray(self._bt),
+                jnp.asarray(pos), q_lens, faults, jnp.asarray(self._temps),
+                jnp.asarray(self._topks), jnp.asarray(self._seeds),
+                jnp.asarray(self._rids), jnp.asarray(self._counters))
+
     def _step_unified(self, faults: Optional[FaultSpec] = None
                       ) -> List[Request]:
         decision = self.scheduler.step(self._try_admit, self._release_request)
@@ -1376,11 +1393,8 @@ class PagedServeEngine(ServeEngine):
         while True:
             is_retry = (efta_retries + kv_retries) > 0
             logits, next_tokens, rep, bad, new_state = self._step_fused(
-                self.params, tok_dev, self.pool.state,
-                jnp.asarray(self._bt), jnp.asarray(self._pos), qlen_dev,
-                attempt_faults, jnp.asarray(self._temps),
-                jnp.asarray(self._topks), jnp.asarray(self._seeds),
-                jnp.asarray(self._rids), jnp.asarray(self._counters))
+                *self._fused_args(tok_dev, self._pos, qlen_dev,
+                                  attempt_faults))
             det_acc += np.asarray(rep.detected, np.int64)
             cor_acc += np.asarray(rep.corrected, np.int64)
             if is_retry:

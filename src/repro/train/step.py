@@ -23,7 +23,6 @@ from repro.distributed.sharding import dp_axes
 from repro.models.api import Model
 from repro.optim.adamw import AdamW
 from repro.train.state import TrainState
-from repro.utils.compat import shard_map
 
 
 def init_state(model: Model, optimizer: AdamW, rng, *, pod_sync="dense"):
@@ -84,7 +83,7 @@ def make_train_step(model: Model, optimizer: AdamW, *, mesh=None,
                 loss = jax.lax.pmean(loss, "pod")
                 return grads, new_ef, loss, metrics
 
-            grads, new_ef, loss, metrics = shard_map(
+            grads, new_ef, loss, metrics = jax.shard_map(
                 per_pod, mesh=mesh,
                 in_specs=(P(), P("pod"), P()),
                 out_specs=(P(), P(), P(), P()),

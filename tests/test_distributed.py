@@ -88,7 +88,6 @@ COMPRESSED = textwrap.dedent("""
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.distributed.collectives import compressed_psum
-    from repro.utils.compat import shard_map
 
     mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("pod", "data"))
     x = jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 7.0
@@ -97,7 +96,7 @@ COMPRESSED = textwrap.dedent("""
     def f(x, err):
         return compressed_psum(x, "pod", err)
 
-    y, new_err = shard_map(
+    y, new_err = jax.shard_map(
         f, mesh=mesh, in_specs=(P("pod", "data"), P(None, "data")),
         out_specs=(P(None, "data"), P(None, "data")), check_vma=False)(x, err)
     ref = np.asarray(x).reshape(4, 1, 8).mean(0)
