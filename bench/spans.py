@@ -1,0 +1,290 @@
+"""The program's own spans and scopes in a profiler trace of the window.
+
+The served step marks its parts on the profiler's host plane as
+``serve.*`` spans (``repro/serve/trace.py``: ``serve.step`` around each
+engine step, and inside it ``serve.schedule``, ``.prepare``,
+``.dispatch``, ``.wait``, ``.repair``, ``.commit``), on the clock the
+device ops of the same trace use. From the planes
+:func:`bench.tracing.reduce` reads, :func:`reduce` gives:
+
+- the ``serve.*`` spans in the window, each with its arguments and its
+  depth in the nesting of spans by interval;
+- idle by span: each device-idle interval of the window split over the
+  self time of the innermost ``serve.*`` span open then, by interval
+  intersection; idle time under no such span goes to ``outside``;
+- the device tail: the time from the last device op to the window's close.
+
+The step program's ops carry its ``jax.named_scope`` names (and the paged
+kernel's ``pallas_call`` name) in their ``op_name`` metadata, e.g.
+``jit(_step_fused_fn)/while/body/closed_call/checkpoint/kv_append/scatter``.
+A TPU trace read through ``jax.profiler.ProfileData`` does not carry it:
+an ``XLA Ops`` event's stats are ``device_offset_ps``,
+``device_duration_ps`` and ``Time Scale Multiplier``, and its name is the
+instruction's HLO text without metadata. The compiled program's text has
+both, under the same instruction names as the trace: :func:`op_scopes`
+maps each name to the innermost program scope of its ``op_name``, and
+:func:`scope_seconds` sums a ``tracing.Summary``'s op self times by scope.
+A scope is matched as a whole component of the ``op_name`` path, never as
+a substring of an op's text, which names operands such as
+``%kv_append.12``.
+
+The harness keeps neither on its ``Summary`` yet, so
+``engine.host_gap_ms`` and ``model.kv_write_ms`` are not in
+``BENCHMARK.json``. Run from the root of a checkout, on the chip,
+
+    python3 -m bench.spans --workload <cell> --seed <n> --seconds <s>
+
+makes the same run as ``bench/run.py --trace 1`` with both metrics and the
+scope of each of the ten ops of most device time in its result line.
+"""
+from __future__ import annotations
+
+import dataclasses
+import re
+import sys
+from pathlib import Path
+from typing import Dict, List, Optional, Tuple
+
+from bench import tracing
+
+PREFIX = "serve."
+STEP = "serve.step"
+OUTSIDE = "outside"
+UNSCOPED = "unscoped"
+# the program's named scopes (models/attention.py, models/transformer.py,
+# serve/paged.py) and the paged kernel's pallas_call name
+SCOPES = ("kv_guard", "kv_append", "efta_paged", "lm_head", "sample")
+# metrics that read what this module adds to a Summary
+METRICS = ("engine.host_gap_ms", "model.kv_write_ms")
+
+
+@dataclasses.dataclass
+class Span:
+    name: str
+    start_ns: float
+    end_ns: float
+    args: dict
+    depth: int                         # 0: no serve.* span encloses it
+
+
+@dataclasses.dataclass
+class Spans:
+    spans: List[Span]                  # in the window, by start
+    idle_s: Dict[str, float]           # span name or OUTSIDE -> seconds
+    longest_gap: Tuple[float, str]     # (seconds, span with most of it)
+    device_tail_s: float               # last device op to window close
+
+    @property
+    def steps(self) -> List[Span]:
+        return [s for s in self.spans if s.name == STEP]
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The innermost program scope in an ``op_name`` path, or None."""
+    for part in reversed(op_name.split("/")):
+        if part in SCOPES:
+            return part
+    return None
+
+
+def _window(planes) -> Tuple[float, float]:
+    for p in planes:
+        if p.name.startswith("/device:"):
+            continue
+        for line in p.lines:
+            for ev in line.events:
+                if ev.name == tracing.WINDOW_SPAN:
+                    return ev.start_ns, ev.start_ns + ev.duration_ns
+    raise ValueError(f"the trace has no {tracing.WINDOW_SPAN} span")
+
+
+def program_spans(planes, w0: float, w1: float) -> List[Span]:
+    """The ``serve.*`` spans that overlap ``w0..w1``, nested by interval."""
+    raw = []
+    for p in planes:
+        if p.name.startswith("/device:"):
+            continue
+        for line in p.lines:
+            for ev in line.events:
+                if ev.name.startswith(PREFIX):
+                    s, e = ev.start_ns, ev.start_ns + ev.duration_ns
+                    if e > w0 and s < w1:
+                        raw.append((s, e, ev.name, dict(ev.stats)))
+    out, open_ends = [], []
+    for s, e, name, args in sorted(raw, key=lambda t: (t[0], -t[1])):
+        while open_ends and open_ends[-1] <= s:
+            open_ends.pop()
+        out.append(Span(name, s, e, args, depth=len(open_ends)))
+        open_ends.append(e)
+    return out
+
+
+def innermost(spans: List[Span], w0: float, w1: float
+              ) -> List[Tuple[float, float, str]]:
+    """``w0..w1`` cut into pieces, each under the innermost span open
+    then (``OUTSIDE`` under none): the spans' self time on a timeline."""
+    pieces: List[Tuple[float, float, str]] = []
+    stack: List[Span] = []
+    t = w0
+
+    def upto(x: float) -> None:
+        nonlocal t
+        x = min(max(x, w0), w1)
+        if x > t:
+            pieces.append((t, x, stack[-1].name if stack else OUTSIDE))
+            t = x
+
+    for sp in spans:
+        while stack and stack[-1].end_ns <= sp.start_ns:
+            upto(stack[-1].end_ns)
+            stack.pop()
+        upto(sp.start_ns)
+        stack.append(sp)
+    while stack:
+        upto(stack[-1].end_ns)
+        stack.pop()
+    upto(w1)
+    return pieces
+
+
+def _split(gaps, pieces) -> List[Dict[str, float]]:
+    """Each gap ``(a, b)`` as ns per piece label, by intersection."""
+    out, i = [], 0
+    for a, b in gaps:
+        while i < len(pieces) and pieces[i][1] <= a:
+            i += 1
+        share: Dict[str, float] = {}
+        j = i
+        while j < len(pieces) and pieces[j][0] < b:
+            s, e, label = pieces[j]
+            ns = min(b, e) - max(a, s)
+            if ns > 0:
+                share[label] = share.get(label, 0.0) + ns
+            j += 1
+        out.append(share)
+    return out
+
+
+def reduce(planes) -> Spans:
+    planes = list(planes)
+    w0, w1 = _window(planes)
+    spans = program_spans(planes, w0, w1)
+    pieces = innermost(spans, w0, w1)
+    idle_ns: Dict[str, float] = {}
+    longest = (0.0, OUTSIDE)
+    devices, last_end = 0, w0
+    for p in planes:
+        if not p.name.startswith("/device:"):
+            continue
+        ops = [(max(ev.start_ns, w0), min(ev.start_ns + ev.duration_ns, w1))
+               for line in p.lines if line.name == tracing.OPS_LINE
+               for ev in line.events]
+        ops = [(s, e) for s, e in ops if e > s]
+        if not ops:
+            continue
+        devices += 1
+        merged = tracing._union(ops)
+        last_end = max(last_end, merged[-1][1])
+        edges = [w0] + [x for iv in merged for x in iv] + [w1]
+        gaps = [(a, b) for a, b in zip(edges[::2], edges[1::2]) if b > a]
+        for (a, b), share in zip(gaps, _split(gaps, pieces)):
+            for label, ns in share.items():
+                idle_ns[label] = idle_ns.get(label, 0.0) + ns
+            if (b - a) * 1e-9 > longest[0]:
+                longest = ((b - a) * 1e-9, max(share, key=share.get))
+    n = max(devices, 1)
+    return Spans(spans=spans,
+                 idle_s={k: v * 1e-9 / n for k, v in idle_ns.items()},
+                 longest_gap=longest,
+                 device_tail_s=(w1 - last_end) * 1e-9 if devices else 0.0)
+
+
+def op_scopes(hlo_text: str) -> Dict[str, str]:
+    """Instruction name -> innermost program scope of its ``op_name``
+    (``UNSCOPED`` where it has none), for every instruction of a compiled
+    program's HLO text."""
+    out = {}
+    for m in re.finditer(r'^\s*(?:ROOT )?%([\w.\-]+) = (.*)$', hlo_text,
+                         re.M):
+        op_name = re.search(r'op_name="([^"]*)"', m.group(2))
+        out[m.group(1)] = (op_name and scope_of(op_name.group(1))
+                           or UNSCOPED)
+    return out
+
+
+def scope_seconds(summary, scopes: Dict[str, str]) -> Dict[str, float]:
+    """Device self seconds of a ``tracing.Summary``'s ops by program scope
+    (``scopes`` from :func:`op_scopes`; ``UNSCOPED`` for the rest)."""
+    out: Dict[str, float] = {}
+    for key, s in summary.op_seconds.items():
+        scope = scopes.get(key, UNSCOPED)
+        out[scope] = out.get(scope, 0.0) + s
+    return out
+
+
+def traced_run(cell, seed: int, seconds: float, **kw):
+    """``bench.run.run_cell`` traced, with :data:`METRICS` added to the
+    cell: the step's two programs are compiled once more when the engine
+    is built, for their HLO text, and the trace's ``Summary`` is given
+    ``spans`` (:func:`reduce` of its planes) and ``scopes`` (the chunk-width
+    program's :func:`op_scopes` over the width-1 program's: the trace keys
+    ops by instruction name alone, and a name both programs use takes the
+    chunk-width program's scope). Returns the result and the ``Summary``."""
+    from bench import catalog, run, system
+
+    texts, kept = [], {}
+    build, reduce_ = system.build_engine, tracing.reduce
+
+    def build_engine(*a, **k):
+        eng = build(*a, **k)
+        texts.extend(eng.lower_step(w).compile().as_text()
+                     for w in (1, eng.chunk_size))
+        return eng
+
+    def reduce_all(planes):
+        planes = list(planes)
+        summary = kept["summary"] = reduce_(planes)
+        summary.spans = reduce(planes)
+        summary.scopes = {k: v for text in texts
+                          for k, v in op_scopes(text).items()}
+        return summary
+
+    extra = tuple(catalog.Metric(
+        name=n, unit="ms", end_to_end=False, moves="itl_p95_ms",
+        workloads=None, read=catalog.load_reader(n)) for n in METRICS)
+    cell = dataclasses.replace(cell, per_layer=cell.per_layer + extra)
+    system.build_engine, tracing.reduce = build_engine, reduce_all
+    try:
+        result = run.run_cell(cell, seed, seconds, True, **kw)
+    finally:
+        system.build_engine, tracing.reduce = build, reduce_
+    summary = kept["summary"]
+    top = sorted(summary.op_seconds.items(), key=lambda kv: -kv[1])[:10]
+    result["op_scopes"] = [[k, summary.scopes.get(k, UNSCOPED)]
+                           for k, _ in top]
+    return result, summary
+
+
+def main(argv=None) -> int:
+    root = Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root / "src"))
+    import json
+
+    from bench import catalog, run
+
+    args = run.parse_args(argv)
+    cell = catalog.load_cell(args.workload, root)
+    run.enable_compile_cache()
+    try:
+        device = run.device_info(cell.chips)
+    except RuntimeError as e:
+        run.say(f"bench: {e}")
+        return 3
+    result, _ = traced_run(cell, args.seed, args.seconds, device=device,
+                           peaks=catalog.peaks(device["kind"]))
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -500,6 +500,7 @@ def efta_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
+        name="efta_attention",
     )(fault, qr, kr, vr)
 
     return out.reshape(b, h, sq, d), rep[:, :, 0, :5].sum(axis=(0, 1))

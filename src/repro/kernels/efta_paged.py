@@ -493,6 +493,7 @@ def efta_paged_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
+        name="efta_paged",
     )(fault, jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(kv_lens, jnp.int32), jnp.asarray(q_lens, jnp.int32), win,
       qr, k_pool, v_pool, k_checks.c1, k_checks.c2, v_checks.c1, v_checks.c2)

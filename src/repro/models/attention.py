@@ -123,43 +123,47 @@ def _paged_chunk(q, k, v, cache: PagedKVCache, *, cfg: EFTAConfig, window,
     off = pos % bs
 
     # -- laundering guard: pre-verify the first touched block's prior rows
-    tgt0 = jnp.take_along_axis(bt, j0[:, None], axis=1)[:, 0]
-    bad_tk, _ = cks.verify_block(
-        cache.k[tgt0], cks.Checksums(cache.kc1[tgt0], cache.kc2[tgt0]), cs,
-        threshold=thr)
-    bad_tv, _ = cks.verify_block(
-        cache.v[tgt0], cks.Checksums(cache.vc1[tgt0], cache.vc2[tgt0]), cs,
-        threshold=thr)
-    tail_bad = (jnp.any(bad_tk | bad_tv, axis=-1) & (tgt0 > 0)
-                & (off > 0) & (q_len > 0))                     # (B,)
+    with jax.named_scope("kv_guard"):
+        tgt0 = jnp.take_along_axis(bt, j0[:, None], axis=1)[:, 0]
+        bad_tk, _ = cks.verify_block(
+            cache.k[tgt0], cks.Checksums(cache.kc1[tgt0], cache.kc2[tgt0]),
+            cs, threshold=thr)
+        bad_tv, _ = cks.verify_block(
+            cache.v[tgt0], cks.Checksums(cache.vc1[tgt0], cache.vc2[tgt0]),
+            cs, threshold=thr)
+        tail_bad = (jnp.any(bad_tk | bad_tv, axis=-1) & (tgt0 > 0)
+                    & (off > 0) & (q_len > 0))                 # (B,)
 
-    # -- scatter the chunk's K/V rows into their blocks (append-before-
-    # attend); padding rows (c >= q_len) divert to the null scratch block
-    c_idx = jnp.arange(c_width, dtype=jnp.int32)
-    p_abs = pos[:, None] + c_idx[None, :]                      # (B, C)
-    valid = c_idx[None, :] < q_len[:, None]
-    jrow = jnp.clip(p_abs // bs, 0, mb - 1)
-    tgt_rows = jnp.where(valid, jnp.take_along_axis(bt, jrow, axis=1), 0)
-    offs = jnp.where(valid, p_abs % bs, 0)
-    row_k = k.transpose(0, 2, 1, 3).astype(cache.k.dtype)      # (B,C,Hkv,hd)
-    row_v = v.transpose(0, 2, 1, 3).astype(cache.v.dtype)
-    new_k = cache.k.at[tgt_rows, :, offs, :].set(row_k)
-    new_v = cache.v.at[tgt_rows, :, offs, :].set(row_v)
+    with jax.named_scope("kv_append"):
+        # -- scatter the chunk's K/V rows into their blocks (append-
+        # before-attend); padding rows (c >= q_len) divert to the null
+        # scratch block
+        c_idx = jnp.arange(c_width, dtype=jnp.int32)
+        p_abs = pos[:, None] + c_idx[None, :]                  # (B, C)
+        valid = c_idx[None, :] < q_len[:, None]
+        jrow = jnp.clip(p_abs // bs, 0, mb - 1)
+        tgt_rows = jnp.where(valid, jnp.take_along_axis(bt, jrow, axis=1), 0)
+        offs = jnp.where(valid, p_abs % bs, 0)
+        row_k = k.transpose(0, 2, 1, 3).astype(cache.k.dtype)  # B,C,Hkv,hd
+        row_v = v.transpose(0, 2, 1, 3).astype(cache.v.dtype)
+        new_k = cache.k.at[tgt_rows, :, offs, :].set(row_k)
+        new_v = cache.v.at[tgt_rows, :, offs, :].set(row_v)
 
-    # -- checksum generation for exactly the blocks the chunk touched (the
-    # first may be partial, the rest start at row 0; untouched -> null)
-    nt = (c_width + bs - 2) // bs + 1      # max blocks a C-row chunk spans
-    jt = j0[:, None] + jnp.arange(nt, dtype=jnp.int32)[None, :]    # (B, nt)
-    last = (pos + jnp.maximum(q_len, 1) - 1) // bs
-    touched = (jt <= last[:, None]) & (q_len[:, None] > 0)
-    tid = jnp.where(
-        touched, jnp.take_along_axis(bt, jnp.clip(jt, 0, mb - 1), axis=1), 0)
-    kc = cks.encode_kv(new_k[tid], cs)                 # (B, nt, Hkv, cs, hd)
-    vc = cks.encode_kv(new_v[tid], cs)
-    kc1 = cache.kc1.at[tid].set(kc.c1)
-    kc2 = cache.kc2.at[tid].set(kc.c2)
-    vc1 = cache.vc1.at[tid].set(vc.c1)
-    vc2 = cache.vc2.at[tid].set(vc.c2)
+        # -- checksum generation for exactly the blocks the chunk touched
+        # (the first may be partial, the rest start at row 0; untouched ->
+        # null)
+        nt = (c_width + bs - 2) // bs + 1  # max blocks a C-row chunk spans
+        jt = j0[:, None] + jnp.arange(nt, dtype=jnp.int32)[None, :]  # B, nt
+        last = (pos + jnp.maximum(q_len, 1) - 1) // bs
+        touched = (jt <= last[:, None]) & (q_len[:, None] > 0)
+        tid = jnp.where(touched, jnp.take_along_axis(
+            bt, jnp.clip(jt, 0, mb - 1), axis=1), 0)
+        kc = cks.encode_kv(new_k[tid], cs)             # (B, nt, Hkv, cs, hd)
+        vc = cks.encode_kv(new_v[tid], cs)
+        kc1 = cache.kc1.at[tid].set(kc.c1)
+        kc2 = cache.kc2.at[tid].set(kc.c2)
+        vc1 = cache.vc1.at[tid].set(vc.c1)
+        vc2 = cache.vc2.at[tid].set(vc.c2)
 
     rep = efta_paged_attention_pallas(
         q, new_k, new_v,

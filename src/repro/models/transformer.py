@@ -426,7 +426,8 @@ def forward(params, cfg: ModelConfig, batch: dict, *, mesh=None,
     x = norm_apply(cfg.norm, params["final_norm"], x)
     x = shard_act(x, mesh)
     table = params.get("lm_head", params["embed"])["table"]
-    logits = unembed(params["embed"], x, table=table)
+    with jax.named_scope("lm_head"):
+        logits = unembed(params["embed"], x, table=table)
     if mesh is not None and "model" in mesh.shape:
         dp = tuple(a for a in DP_AXES if a in mesh.shape)
         logits = shard_act(logits, mesh, P(dp, None, "model"))

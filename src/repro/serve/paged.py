@@ -99,6 +99,7 @@ from repro.serve.engine import ServeEngine
 from repro.serve.sampling import (request_key, sample_tokens,
                                   speculative_accept)
 from repro.serve.scheduler import Request
+from repro.serve.trace import span
 
 
 class PagedKVState(NamedTuple):
@@ -476,16 +477,18 @@ class PagedServeEngine(ServeEngine):
         bad = jnp.any(nc.bad > 0, axis=0)                  # (ns, mb)
         new_state = PagedKVState(k=nc.k, v=nc.v, kc1=nc.kc1, kc2=nc.kc2,
                                  vc1=nc.vc1, vc2=nc.vc2)
-        idx = jnp.clip(q_lens - 1, 0, chunk - 1)
-        last = jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0]
 
         def key_of(seed, rid, counter):
             return jax.random.fold_in(
                 jax.random.fold_in(jax.random.PRNGKey(seed), rid), counter)
 
-        keys = jax.vmap(key_of)(seeds, rids, counters)
-        next_tokens = sample_tokens(last, temperature=temps, top_k=topks,
-                                    keys=keys)
+        with jax.named_scope("sample"):
+            idx = jnp.clip(q_lens - 1, 0, chunk - 1)
+            last = jnp.take_along_axis(logits, idx[:, None, None],
+                                       axis=1)[:, 0]
+            keys = jax.vmap(key_of)(seeds, rids, counters)
+            next_tokens = sample_tokens(last, temperature=temps,
+                                        top_k=topks, keys=keys)
         # the full per-row plane only leaves the program when an acceptance
         # stage will read it — with speculation off (trace-static) the
         # non-speculative hot path pays nothing for the generalization
@@ -1289,10 +1292,16 @@ class PagedServeEngine(ServeEngine):
         verified, and a mismatch triggers block re-prefill + step retry
         before anything is committed. The fused backend runs the unified
         mixed prefill/decode batched step; the gather backend the
-        single-token decode step (its prompts prefill at admission)."""
-        if self.kernel == "fused":
-            return self._step_unified(faults)
-        return self._step_gather(faults)
+        single-token decode step (its prompts prefill at admission).
+
+        The step runs inside the profiler span ``serve.step`` (see
+        :mod:`repro.serve.trace`); on the fused backend its parts have
+        spans of their own and ``serve.step`` carries the program ``width``,
+        the requests fed (``running``), the rows fed and the ``attempts``."""
+        with span("step") as step_span:
+            if self.kernel == "fused":
+                return self._step_unified(faults, step_span)
+            return self._step_gather(faults)
 
     def lower_step(self, chunk: int):
         """Lower the fused unified step at chunk width ``chunk`` against the
@@ -1315,86 +1324,103 @@ class PagedServeEngine(ServeEngine):
                 jnp.asarray(self._topks), jnp.asarray(self._seeds),
                 jnp.asarray(self._rids), jnp.asarray(self._counters))
 
-    def _step_unified(self, faults: Optional[FaultSpec] = None
-                      ) -> List[Request]:
-        decision = self.scheduler.step(self._try_admit, self._release_request)
-        for req in decision.admitted:
-            self._admit(req)
-        finished = list(decision.evicted)
-        for r in self.scheduler.active_rows():
-            if r.is_done() and r.slot is not None:
-                # finished at admission; computes garbage until evicted next
-                # iteration — park its writes on the null block
-                self._bt[r.slot] = 0
-                self._pos[r.slot] = 0
-                self._queue[r.slot] = []
-        active_reqs = [r for r in self.scheduler.active_rows()
-                       if not r.is_done()]
-        if not active_reqs:
-            return finished
+    def _step_unified(self, faults: Optional[FaultSpec],
+                      step_span) -> List[Request]:
+        with span("schedule"):
+            decision = self.scheduler.step(self._try_admit,
+                                           self._release_request)
+            for req in decision.admitted:
+                self._admit(req)
+            finished = list(decision.evicted)
+            for r in self.scheduler.active_rows():
+                if r.is_done() and r.slot is not None:
+                    # finished at admission; computes garbage until evicted
+                    # next iteration — park its writes on the null block
+                    self._bt[r.slot] = 0
+                    self._pos[r.slot] = 0
+                    self._queue[r.slot] = []
+            active_reqs = [r for r in self.scheduler.active_rows()
+                           if not r.is_done()]
+            if not active_reqs:
+                return finished
 
-        # chunk plan: one token per request unconditionally (decodes never
-        # starve), prompt surplus FCFS within the scheduler's chunk budget;
-        # with speculation on, steady-state decodes additionally propose up
-        # to draft_len candidate rows from the leftover budget (prompt
-        # chunks rank first — speculation never starves admissions)
-        demands = [(r, len(self._queue[r.slot])) for r in active_reqs]
-        spec_tokens: Dict[int, np.ndarray] = {}
-        if self._proposer is not None:
-            wants = {r.rid: self._spec_cap(r) for r in active_reqs}
-            grants, draft_grants = self.scheduler.plan_chunks(
-                demands, self.chunk_size, draft_wants=wants)
-            spec_tokens = self._propose_drafts(active_reqs, draft_grants)
-        else:
-            grants = self.scheduler.plan_chunks(demands, self.chunk_size)
-        for r in list(active_reqs):
-            need = grants[r.rid] + len(spec_tokens.get(r.slot, ()))
-            if r.slot is not None and need > 0:
-                self._ensure_capacity(r, need)
-        active_reqs = [r for r in active_reqs
-                       if r.slot is not None and not r.is_done()]
-        spec_tokens = {s: d for s, d in spec_tokens.items()
-                       if any(r.slot == s for r in active_reqs)}
-        if not active_reqs:
-            return finished
+            # chunk plan: one token per request unconditionally (decodes
+            # never starve), prompt surplus FCFS within the scheduler's
+            # chunk budget; with speculation on, steady-state decodes
+            # additionally propose up to draft_len candidate rows from the
+            # leftover budget (prompt chunks rank first — speculation never
+            # starves admissions)
+            demands = [(r, len(self._queue[r.slot])) for r in active_reqs]
+            spec_tokens: Dict[int, np.ndarray] = {}
+            if self._proposer is not None:
+                wants = {r.rid: self._spec_cap(r) for r in active_reqs}
+                grants, draft_grants = self.scheduler.plan_chunks(
+                    demands, self.chunk_size, draft_wants=wants)
+                spec_tokens = self._propose_drafts(active_reqs, draft_grants)
+            else:
+                grants = self.scheduler.plan_chunks(demands, self.chunk_size)
+            for r in list(active_reqs):
+                need = grants[r.rid] + len(spec_tokens.get(r.slot, ()))
+                if r.slot is not None and need > 0:
+                    self._ensure_capacity(r, need)
+            active_reqs = [r for r in active_reqs
+                           if r.slot is not None and not r.is_done()]
+            spec_tokens = {s: d for s, d in spec_tokens.items()
+                           if any(r.slot == s for r in active_reqs)}
+            if not active_reqs:
+                return finished
         active = [r.slot for r in active_reqs]
         by_slot = {r.slot: r for r in active_reqs}
 
-        # pure-decode steps run the width-1 program; any prefill surplus or
-        # draft row promotes the step to the chunk-width program (the only
-        # two shapes this engine ever compiles — draft K pads to the chunk)
-        chunk = self.chunk_size if (spec_tokens or any(
-            grants[r.rid] > 1 for r in active_reqs)) else 1
-        tokens = np.zeros((self.n_slots, chunk), np.int32)
-        q_lens = np.zeros((self.n_slots,), np.int32)
-        for r in active_reqs:
-            g = grants[r.rid]
-            tokens[r.slot, :g] = self._queue[r.slot][:g]
-            d = spec_tokens.get(r.slot)
-            if d is not None:
-                tokens[r.slot, g:g + len(d)] = d
-                g += len(d)
-            q_lens[r.slot] = g
+        with span("prepare"):
+            # pure-decode steps run the width-1 program; any prefill surplus
+            # or draft row promotes the step to the chunk-width program (the
+            # only two shapes this engine ever compiles — draft K pads to
+            # the chunk)
+            chunk = self.chunk_size if (spec_tokens or any(
+                grants[r.rid] > 1 for r in active_reqs)) else 1
+            tokens = np.zeros((self.n_slots, chunk), np.int32)
+            q_lens = np.zeros((self.n_slots,), np.int32)
+            for r in active_reqs:
+                g = grants[r.rid]
+                tokens[r.slot, :g] = self._queue[r.slot][:g]
+                d = spec_tokens.get(r.slot)
+                if d is not None:
+                    tokens[r.slot, g:g + len(d)] = d
+                    g += len(d)
+                q_lens[r.slot] = g
+            tok_dev = jnp.asarray(tokens)
+            qlen_dev = jnp.asarray(q_lens)
+            args = self._fused_args(tok_dev, self._pos, qlen_dev,
+                                    self._no_faults if faults is None
+                                    else faults)
 
-        if faults is None:
-            faults = self._no_faults
         kv_det = np.zeros((self.n_slots,), np.int64)
         kv_cor = np.zeros((self.n_slots,), np.int64)
         efta_retries = 0
         kv_retries = 0
-        attempt_faults = faults
         det_acc = np.zeros((self.n_slots, 5), np.int64)
         cor_acc = np.zeros((self.n_slots, 5), np.int64)
         redet_acc = np.zeros((self.n_slots, 5), np.int64)
         kv_redet = np.zeros((self.n_slots,), np.int64)
         seen_bad: set = set()
-        tok_dev = jnp.asarray(tokens)
-        qlen_dev = jnp.asarray(q_lens)
         while True:
             is_retry = (efta_retries + kv_retries) > 0
-            logits, next_tokens, rep, bad, new_state = self._step_fused(
-                *self._fused_args(tok_dev, self._pos, qlen_dev,
-                                  attempt_faults))
+            if is_retry:
+                with span("prepare"):
+                    args = self._fused_args(tok_dev, self._pos, qlen_dev,
+                                            self._no_faults)
+            with span("dispatch"):
+                logits, next_tokens, rep, bad, new_state = \
+                    self._step_fused(*args)
+            # drop this hold on the pre-step pool: ``self.pool.state`` keeps
+            # it for a retry, and the commit frees it, well before the next
+            # step allocates its own output pool
+            del args
+            with span("wait"):
+                # every output the host reads is ready after this: the reads
+                # below copy, they do not wait for the device
+                jax.block_until_ready((logits, next_tokens, rep, bad))
             det_acc += np.asarray(rep.detected, np.int64)
             cor_acc += np.asarray(rep.corrected, np.int64)
             if is_retry:
@@ -1418,21 +1444,22 @@ class PagedServeEngine(ServeEngine):
                     len(bad_bids - seen_bad)
                 seen_bad |= bad_bids
                 healed: set = set()
-                for s in kv_hit_slots:
-                    idxs = np.flatnonzero(bad_np[s])
-                    kv_cor[s] += idxs.size
-                    self._repair_blocks(by_slot[s], idxs, healed=healed)
+                with span("repair"):
+                    for s in kv_hit_slots:
+                        idxs = np.flatnonzero(bad_np[s])
+                        kv_cor[s] += idxs.size
+                        self._repair_blocks(by_slot[s], idxs, healed=healed)
                 if kv_retries < max(1, self.max_retries):
                     kv_retries += 1
-                    attempt_faults = self._no_faults
                     continue
             if self._needs_retry_rows(rep, rows=active) and \
                     efta_retries < self.max_retries:
                 efta_retries += 1
-                attempt_faults = self._no_faults
                 continue
             break
         retries = efta_retries + kv_retries
+        step_span.set_metadata(width=chunk, running=len(active_reqs),
+                               rows=int(q_lens.sum()), attempts=retries + 1)
 
         if kv_hit_slots:
             # the FINAL attempt still read poisoned KV: see _step_gather —
@@ -1457,82 +1484,83 @@ class PagedServeEngine(ServeEngine):
                     "SEU; cordon this host and restart elsewhere")
             return finished
 
-        # commit
-        self._poisoned_steps = 0
-        self.pool.state = new_state
-        next_np = np.asarray(next_tokens)
-        logits_np = np.asarray(logits) if spec_tokens else None
-        per_request = {}
-        rollback_plan: Dict[int, Tuple[int, int]] = {}
-        bs = self.block_size
-        for req in active_reqs:
-            slot = req.slot
-            g = int(q_lens[slot])
-            old_pos = int(self._pos[slot])
-            new_pos = old_pos + g
-            req.retries += retries
-            d = spec_tokens.get(slot)
-            if d is not None:
-                # accept: commit the longest valid draft prefix + the bonus/
-                # resample token; rewind the slot past the rejected rows
-                # (the KV rollback below truncates them on-device)
-                k = len(d)
-                committed_drafts, bonus = self._accept_slot(
-                    req, logits_np[slot, :k + 1], d)
-                a = len(committed_drafts)
-                keep_pos = old_pos + 1 + a     # pending row + accepted rows
-                for bi in range(old_pos // bs,
-                                min((new_pos - 1) // bs + 1,
-                                    len(req.block_ids))):
-                    self.pool.blocks.note_write(req.block_ids[bi])
-                req.generated.extend(committed_drafts)
-                if bonus is not None:
-                    req.generated.append(bonus)
-                self._queue[slot] = [] if bonus is None else [bonus]
-                self._counters[slot] = req.num_generated
-                self._pos[slot] = keep_pos
-                n_new = a + (0 if bonus is None else 1)
-                self.stats.tokens += n_new
-                self.paged_stats.spec_proposed_tokens += k
-                self.paged_stats.spec_accepted_tokens += a
-                self.telemetry.observe_draft(
-                    req.rid, np.zeros(6, np.int64), np.zeros(6, np.int64),
-                    proposed=k, accepted=a)
-                if keep_pos < new_pos:
-                    rollback_plan[slot] = (keep_pos, new_pos)
-                self._register_full_blocks(req, old_pos, keep_pos)
-            elif g:
-                if g > 1:
-                    self.paged_stats.chunked_prefill_tokens += g
-                # the chunk rewrote these blocks: their generations move
-                # (and the prefix cache learns any block it completed)
-                for bi in range(old_pos // bs,
-                                min((new_pos - 1) // bs + 1,
-                                    len(req.block_ids))):
-                    self.pool.blocks.note_write(req.block_ids[bi])
-                del self._queue[slot][:g]
-                self._pos[slot] = new_pos
-                if not self._queue[slot]:
-                    # queue drained: this chunk's last row produced the next
-                    # token (first sample for a fresh prompt, the steady-
-                    # state decode sample otherwise)
-                    tok = int(next_np[slot])
-                    req.generated.append(tok)
-                    self._queue[slot] = [tok]
+        with span("commit"):
+            self._poisoned_steps = 0
+            self.pool.state = new_state
+            next_np = np.asarray(next_tokens)
+            logits_np = np.asarray(logits) if spec_tokens else None
+            per_request = {}
+            rollback_plan: Dict[int, Tuple[int, int]] = {}
+            bs = self.block_size
+            for req in active_reqs:
+                slot = req.slot
+                g = int(q_lens[slot])
+                old_pos = int(self._pos[slot])
+                new_pos = old_pos + g
+                req.retries += retries
+                d = spec_tokens.get(slot)
+                if d is not None:
+                    # accept: commit the longest valid draft prefix + the
+                    # bonus/resample token; rewind the slot past the
+                    # rejected rows (the KV rollback below truncates them
+                    # on-device)
+                    k = len(d)
+                    committed_drafts, bonus = self._accept_slot(
+                        req, logits_np[slot, :k + 1], d)
+                    a = len(committed_drafts)
+                    keep_pos = old_pos + 1 + a   # pending + accepted rows
+                    for bi in range(old_pos // bs,
+                                    min((new_pos - 1) // bs + 1,
+                                        len(req.block_ids))):
+                        self.pool.blocks.note_write(req.block_ids[bi])
+                    req.generated.extend(committed_drafts)
+                    if bonus is not None:
+                        req.generated.append(bonus)
+                    self._queue[slot] = [] if bonus is None else [bonus]
                     self._counters[slot] = req.num_generated
-                    self.stats.tokens += 1
-                self._register_full_blocks(req, old_pos, new_pos)
-            per_request[req.rid] = (
-                np.concatenate([det_acc[slot], kv_det[slot:slot + 1]]),
-                np.concatenate([cor_acc[slot], kv_cor[slot:slot + 1]]),
-                np.concatenate([redet_acc[slot], kv_redet[slot:slot + 1]]))
-        if spec_tokens:
-            self.paged_stats.spec_steps += 1
-        if rollback_plan:
-            self._apply_rollback(rollback_plan, by_slot)
-        self.telemetry.observe_step(per_request, retries=retries)
-        self.stats.steps += 1
-        self.stats.retries += retries
+                    self._pos[slot] = keep_pos
+                    n_new = a + (0 if bonus is None else 1)
+                    self.stats.tokens += n_new
+                    self.paged_stats.spec_proposed_tokens += k
+                    self.paged_stats.spec_accepted_tokens += a
+                    self.telemetry.observe_draft(
+                        req.rid, np.zeros(6, np.int64), np.zeros(6, np.int64),
+                        proposed=k, accepted=a)
+                    if keep_pos < new_pos:
+                        rollback_plan[slot] = (keep_pos, new_pos)
+                    self._register_full_blocks(req, old_pos, keep_pos)
+                elif g:
+                    if g > 1:
+                        self.paged_stats.chunked_prefill_tokens += g
+                    # the chunk rewrote these blocks: their generations move
+                    # (and the prefix cache learns any block it completed)
+                    for bi in range(old_pos // bs,
+                                    min((new_pos - 1) // bs + 1,
+                                        len(req.block_ids))):
+                        self.pool.blocks.note_write(req.block_ids[bi])
+                    del self._queue[slot][:g]
+                    self._pos[slot] = new_pos
+                    if not self._queue[slot]:
+                        # queue drained: this chunk's last row produced the
+                        # next token (first sample for a fresh prompt, the
+                        # steady-state decode sample otherwise)
+                        tok = int(next_np[slot])
+                        req.generated.append(tok)
+                        self._queue[slot] = [tok]
+                        self._counters[slot] = req.num_generated
+                        self.stats.tokens += 1
+                    self._register_full_blocks(req, old_pos, new_pos)
+                per_request[req.rid] = (
+                    np.concatenate([det_acc[slot], kv_det[slot:slot + 1]]),
+                    np.concatenate([cor_acc[slot], kv_cor[slot:slot + 1]]),
+                    np.concatenate([redet_acc[slot], kv_redet[slot:slot + 1]]))
+            if spec_tokens:
+                self.paged_stats.spec_steps += 1
+            if rollback_plan:
+                self._apply_rollback(rollback_plan, by_slot)
+            self.telemetry.observe_step(per_request, retries=retries)
+            self.stats.steps += 1
+            self.stats.retries += retries
         return finished
 
     def _step_gather(self, faults: Optional[FaultSpec] = None

@@ -12,6 +12,7 @@ one process may load the TPU compiler library, and under pytest-xdist every
 worker imports this module.
 """
 import os
+import re
 
 import pytest
 
@@ -28,6 +29,15 @@ def one_chip():
     except Exception as e:  # no TPU compiler library on this host
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+def _assert_kernel_named(hlo: str, name: str) -> None:
+    """The kernel's custom call carries ``name`` as a component of its
+    ``op_name`` metadata, where a trace reader finds it."""
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    paths = [m for line in calls
+             for m in re.findall(r'op_name="([^"]+)"', line)]
+    assert paths and all(name in p.split("/") for p in paths), paths
 
 
 def _spec(sharding, shape, dtype):
@@ -66,6 +76,7 @@ def test_paged_kernel_compiles_for_v5e(one_chip, heads, kv_heads, chunk):
             _spec(one_chip, (8,), jnp.int32)]
     compiled = jax.jit(step).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _assert_kernel_named(compiled.as_text(), "efta_paged")
 
 
 def test_efta_attention_kernel_compiles_for_v5e(one_chip):
@@ -80,3 +91,4 @@ def test_efta_attention_kernel_compiles_for_v5e(one_chip):
         q, k, v, cfg=cfg, causal=True, interpret=False)).lower(
             x, x, x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _assert_kernel_named(compiled.as_text(), "efta_attention")
