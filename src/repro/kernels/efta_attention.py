@@ -9,9 +9,12 @@ Architecture mapping (DESIGN.md §2):
   * grid = (batch·heads, Sq/Br, Skv/Bc); the KV axis is ``arbitrary``
     (sequential) so running (m, ℓ, O, O_checksums) accumulate in VMEM scratch
     across KV steps — the Pallas analogue of the paper's intra-CTA loop.
-  * checksum folds use *static strided slices* at lane-tile boundaries
-    (``s = 128`` → each fold term is a whole-vreg add; ``s = 8`` reproduces the
-    paper's MMA-atom stride for fidelity experiments).
+  * checksum folds shift the tile onto itself (``pltpu.roll``) in a tree
+    of fan-in four, so every fold keeps the tile's shape and lane density
+    (``s = 8`` reproduces the paper's MMA-atom stride for fidelity
+    experiments). The same helpers fold each page of the paged kernel's
+    multi-page compute block (``repro.kernels.efta_paged``): a KV tile
+    here is one page.
   * fault injection is a scalar-prefetch descriptor (SEU model): a single bit
     of a chosen tile element is XOR-flipped at a chosen (site, kv-block).
 
@@ -20,7 +23,7 @@ code compiles through Mosaic for a TPU v5e in bf16 at head_dim 64 and
 sequence 1024 (``tests/test_tpu_compile.py``, against a described chip); it
 runs compiled whenever the backend is a TPU (:func:`resolve_interpret`).
 Mosaic-specific choices: report tiles are whole (8, 128) int32 tiles,
-corrections rebuild the tile from segments instead of
+corrections spread the delta over the tile's segments by shifts instead of
 ``dynamic_update_slice``, and the shadow rowmax / rowsum differ from the
 primary by their reduction order (Mosaic has no ``optimization_barrier``).
 """
@@ -84,62 +87,140 @@ def _site_hit(fault_ref, site: Site, *, bh, blk):
             & (fault_ref[F_BLOCK] == blk))
 
 
-def _fold_slices(tile, stride: int, weighted: bool):
-    """Strided fold along the last dim via static lane-tile slices.
+def _radices(segments: int):
+    """Factors of ``segments``, fours first, each the fan-in of one level
+    of :func:`_fold`'s tree."""
+    out, rest = [], segments
+    while rest > 1:
+        k = next(f for f in (4, 2, 3, *range(5, rest + 1)) if rest % f == 0)
+        out.append(k)
+        rest //= k
+    return out
 
-    tile: (R, W) -> (R, stride). Each term is a whole-tile add when
-    ``stride % 128 == 0`` — the TPU analogue of the paper's intra-thread
-    strided accumulation (zero cross-lane shuffles).
+
+def _fold(x, stride: int, segments: int, *, axis: int, op=jnp.add,
+          weighted: bool = False):
+    """The strided checksum fold of every *page* of ``x`` along ``axis``,
+    in place. A page is ``segments`` consecutive segments of ``stride``
+    indices; at each *fold index* (offset below ``stride`` within its
+    page) the result is ``op`` over the page's segments there, segment
+    ``l`` weighted ``l + 1`` when ``weighted`` (``op`` is then the sum).
+    The contiguous kernel's KV tile is one page; the paged kernel's compute
+    block is ``P`` pages of ``block_size`` keys, each folded on its own.
+
+    Segments come in by circular shifts of the whole tile (``pltpu.roll``),
+    which keep its shape and lane density whatever the stride, in a tree
+    of fan-in four: the shifts of one level are independent of each other,
+    so a fold of four segments is one level deep, and one of 32 three.
+    Indices other than the fold indices hold values no check reads. A
+    negative ``stride`` shifts the other way (see :func:`_spread`).
     """
-    w = tile.shape[-1]
-    g = w // stride
-    acc = jnp.zeros((tile.shape[0], stride), jnp.float32)
-    for l in range(g):
-        seg = tile[:, l * stride:(l + 1) * stride].astype(jnp.float32)
-        acc = acc + (float(l + 1) * seg if weighted else seg)
-    return acc
+    n = x.shape[axis]
+
+    def back(y, segs):        # y[i + segs * stride], circularly
+        return pltpu.roll(y, (n - segs * stride) % n, axis) if segs else y
+
+    # acc: op over the m segments from each index; wsum: the sum of
+    # (segment - first) * value over them, when weighted
+    acc, wsum, m = x, None, 1
+    for k in _radices(segments):
+        parts = [back(acc, t * m) for t in range(k)]
+        if weighted:
+            terms = [float(t * m) * parts[t] for t in range(1, k)]
+            if wsum is not None:
+                terms += [back(wsum, t * m) for t in range(k)]
+            wsum = functools.reduce(jnp.add, terms)
+        acc = functools.reduce(op, parts)
+        m *= k
+    return acc + wsum if weighted and wsum is not None else acc
 
 
-def _shadow_rowmax(tile, stride: int):
+def _spread(x, stride: int, segments: int):
+    """Each fold index's value of ``x`` copied to the same offset of every
+    segment of its page, along the last axis; ``x`` must be zero off the
+    fold indices. This is the unweighted fold with its shifts reversed:
+    ``out[i] = sum_l x[i - l * stride]``."""
+    return _fold(x, -stride, segments, axis=x.ndim - 1)
+
+
+def _fold_index(shape, stride: int, page: int):
+    """Lanes at a fold index of their page (see :func:`_fold`)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return lane % page < stride
+
+
+def _correct(tile, d1, d2, bad, stride: int, segments: int):
+    """Paper §4.1 correction of every page of ``tile`` along its last axis:
+    at each flagged fold index (``bad``; ``d1``/``d2`` the unweighted and
+    weighted checksum deltas there) locate the segment ``l*`` from the
+    delta ratio and add ``d1`` back at that segment."""
+    safe = jnp.where(bad, d1, 1.0)
+    l_star = jnp.clip(jnp.round(d2 / safe) - 1, 0, segments - 1)
+    at = _spread(jnp.where(bad, l_star + 1.0, 0.0), stride, segments)
+    add = _spread(jnp.where(bad, d1, 0.0), stride, segments)
+    lane = jax.lax.broadcasted_iota(jnp.int32, tile.shape, tile.ndim - 1)
+    seg = (lane % (stride * segments)) // stride
+    return tile + jnp.where(at == (seg + 1).astype(at.dtype), add, 0.0)
+
+
+def _shadow_rowmax(tile, stride: int, segments: int):
     """Row max of ``tile`` through another reduction tree (segment-wise
     maxima first). Mosaic has no lowering for ``optimization_barrier``, so
     the shadow recompute is kept distinct from the primary by its form: the
     two are different expressions, which common-subexpression elimination
     does not merge, and max is exact, so they agree bit for bit unless a
     fault strikes one of them."""
-    acc = tile[:, :stride]
-    for l in range(1, tile.shape[-1] // stride):
-        acc = jnp.maximum(acc, tile[:, l * stride:(l + 1) * stride])
-    return jnp.max(acc, axis=1, keepdims=True)
+    return jnp.max(_fold(tile, stride, segments, axis=1, op=jnp.maximum),
+                   axis=1, keepdims=True)
 
 
-def _shadow_rowsum(tile, stride: int):
-    """Row sum of ``tile`` folded by segments first (see _shadow_rowmax);
-    the summation order differs, so it agrees with the primary up to f32
-    rounding, far inside the shadow-rowsum tolerance."""
-    return jnp.sum(_fold_slices(tile, stride, weighted=False), axis=1,
-                   keepdims=True)
+def _shadow_rowsum(tile, stride: int, segments: int):
+    """Row sum of ``tile`` folded by pages and segments first (see
+    _shadow_rowmax); the summation order differs, so it agrees with the
+    primary up to f32 rounding, far inside the shadow-rowsum tolerance."""
+    idx = _fold_index(tile.shape, stride, stride * segments)
+    return jnp.sum(jnp.where(idx, _fold(tile, stride, segments, axis=1),
+                             0.0), axis=1, keepdims=True)
 
 
-def _fold_prod(tile, stride: int):
-    w = tile.shape[-1]
-    g = w // stride
-    acc = jnp.ones((tile.shape[0], stride), jnp.float32)
-    for l in range(g):
-        acc = acc * tile[:, l * stride:(l + 1) * stride].astype(jnp.float32)
-    return acc
-
-
-def _correct_strided(tile, d1, d2, bad, stride: int):
-    """Locate (segment l* from the weighted/unweighted delta ratio) and add
-    the delta back — paper §4.1 correction, vectorized per fold segment."""
-    g = tile.shape[-1] // stride
-    safe = jnp.where(bad, d1, 1.0)
-    l_star = jnp.clip(jnp.round(d2 / safe) - 1, 0, g - 1).astype(jnp.int32)
+def _widen(x, width: int):
+    """``x`` padded with zeros along its last axis to ``width``."""
+    pad = width - x.shape[-1]
+    if not pad:
+        return x
     return jnp.concatenate(
-        [tile[:, l * stride:(l + 1) * stride]
-         + jnp.where(bad & (l_star == l), d1, 0.0) for l in range(g)],
-        axis=-1)
+        [x, jnp.zeros(x.shape[:-1] + (pad,), x.dtype)], axis=-1)
+
+
+def _col_checksums(v, s_out: int):
+    """V's column checksums for the output verification (``encode_cols``),
+    through 0/1 and 1..g fold matrices on the MXU: feature ``f`` of V lands
+    on fold lane ``f % s_out`` with weight ``f // s_out + 1``; lanes from
+    ``s_out`` on are zero. Exact for bf16 V; float32 V takes the
+    full-precision passes."""
+    d = v.shape[-1]
+    feat = jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (d, d), 1)
+    on = (feat % s_out == col) & (col < s_out)
+    prec = jax.lax.Precision.HIGHEST if v.dtype == jnp.float32 else None
+    return tuple(
+        jax.lax.dot_general(v, f.astype(v.dtype), (((1,), (0,)), ((), ())),
+                            precision=prec,
+                            preferred_element_type=jnp.float32)
+        for f in (on, jnp.where(on, feat // s_out + 1, 0)))
+
+
+def _verify_output(o, oc1, oc2, *, s_out: int, eps3: float, correct: bool):
+    """Unified output verification (Alg. 1 l.22-24): fold ``o`` and compare
+    with its carried checksums ``oc1``/``oc2`` (already divided by ℓ);
+    returns the flagged fold count and ``o``, corrected in correct mode."""
+    g = o.shape[-1] // s_out
+    d1 = oc1 - _fold(o, s_out, g, axis=1)
+    d2 = oc2 - _fold(o, s_out, g, axis=1, weighted=True)
+    bad = ~(jnp.abs(d1) <= eps3) & _fold_index(o.shape, s_out, o.shape[-1])
+    if correct:
+        o = _correct(o, d1, d2, bad, s_out, g)
+    return bad.sum(dtype=jnp.int32), o
 
 
 def _efta_kernel(
@@ -230,30 +311,26 @@ def _efta_kernel(
             # NaN/inf zero out and the checksum delta restores them exactly.
             s = jnp.where(jnp.isfinite(s), jnp.clip(s, -1e6, 1e6), 0.0)
 
+        # the fold indices of the tile, one page: where the checks read
+        fold_idx = _fold_index(s.shape, s_kv, block_kv)
         if ft:
             # CCG: tensor checksums of K (strided fold along the key axis is
-            # a fold along *rows* of K — sublane adds), then one skinny GEMM.
-            g = block_kv // s_kv
-            kc1 = jnp.zeros((s_kv, k.shape[-1]), jnp.float32)
-            kc2 = jnp.zeros((s_kv, k.shape[-1]), jnp.float32)
-            for l in range(g):
-                seg = k[l * s_kv:(l + 1) * s_kv, :].astype(jnp.float32)
-                kc1 = kc1 + seg
-                kc2 = kc2 + float(l + 1) * seg
-            sc1 = jax.lax.dot_general(
-                q.astype(jnp.float32), kc1, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale  # (Br, s_kv)
-            sc2 = jax.lax.dot_general(
-                q.astype(jnp.float32), kc2, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            sum1 = _fold_slices(s, s_kv, weighted=False)
-            sum2 = _fold_slices(s, s_kv, weighted=True)
-            d1 = sc1 - sum1
-            d2 = sc2 - sum2
-            bad = jnp.abs(d1) > eps1
+            # a fold along *rows* of K), then one skinny GEMM each, whose
+            # (Br, s_kv) result is widened to the tile: fold lane r holds
+            # fold r.
+            kf = k.astype(jnp.float32)
+            sc1, sc2 = (_widen(jax.lax.dot_general(
+                q.astype(jnp.float32),
+                _fold(kf, s_kv, g_kv, axis=0, weighted=w)[:s_kv],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale, block_kv)
+                for w in (False, True))
+            d1 = sc1 - _fold(s, s_kv, g_kv, axis=1)
+            d2 = sc2 - _fold(s, s_kv, g_kv, axis=1, weighted=True)
+            bad = (jnp.abs(d1) > eps1) & fold_idx
             det_scr[0] += bad.sum(dtype=jnp.int32)
             if correct:
-                s = _correct_strided(s, d1, d2, bad, s_kv)
+                s = _correct(s, d1, d2, bad, s_kv, g_kv)
 
         # ---- mask, running max ------------------------------------------
         rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -270,7 +347,7 @@ def _efta_kernel(
         m_new = _flip(m_new, on=_site_hit(fault_ref, Site.ROWMAX, bh=bh, blk=jk),
                       row=fault_row, col=jnp.int32(0), bit=fault_ref[F_BIT])
         if ft and shadow_rowmax:
-            m_chk = jnp.maximum(m_prev, _shadow_rowmax(s_m, s_kv))
+            m_chk = jnp.maximum(m_prev, _shadow_rowmax(s_m, s_kv, g_kv))
             bad_m = m_new != m_chk
             det_scr[2] += bad_m.sum(dtype=jnp.int32)
             if correct:
@@ -286,21 +363,18 @@ def _efta_kernel(
                       row=fault_row, col=fault_ref[F_COL], bit=fault_ref[F_BIT])
         if ft:
             pc1 = jnp.exp(jnp.minimum(sc1 - g_kv * m_sub, cap * g_kv))
-            prod = _fold_prod(p_raw, s_kv)
+            prod = _fold(p_raw, s_kv, g_kv, axis=1, op=jnp.multiply)
             ref = jnp.maximum(jnp.abs(pc1), 1e-20)
             bad_e = jnp.abs(prod - pc1) > eps2 * ref + 1e-20
-            capped = (s - m_sub) > (cap - 1e-3)
-            col_ok = jnp.ones((s.shape[0], s_kv), dtype=bool)
-            for l in range(g_kv):
-                col_ok &= ~capped[:, l * s_kv:(l + 1) * s_kv]
-            bad_e &= col_ok
+            capped = ((s - m_sub) > (cap - 1e-3)).astype(jnp.float32)
+            any_capped = _fold(capped, s_kv, g_kv, axis=1,
+                               op=jnp.maximum) > 0
+            bad_e &= fold_idx & ~any_capped
             det_scr[1] += bad_e.sum(dtype=jnp.int32)
             if correct:
                 recomputed = jnp.exp(jnp.minimum(s - m_sub, cap))
-                p_raw = jnp.concatenate(
-                    [jnp.where(bad_e, recomputed[:, l * s_kv:(l + 1) * s_kv],
-                               p_raw[:, l * s_kv:(l + 1) * s_kv])
-                     for l in range(g_kv)], axis=-1)
+                redo = _spread(bad_e.astype(jnp.float32), s_kv, g_kv) > 0
+                p_raw = jnp.where(redo, recomputed, p_raw)
         if ft and shadow_rowmax and correct:
             # Exact recompute backstop (beyond-paper, mirrors the jnp path):
             # EXP corruptions whose fold product underflows (g_kv segments of
@@ -322,7 +396,8 @@ def _efta_kernel(
                       row=fault_row, col=jnp.int32(0), bit=fault_ref[F_BIT])
         l_scr[...] = l_new
         if ft and shadow_rowsum:
-            lsh_scr[...] = alpha * lsh_scr[...] + _shadow_rowsum(p, s_kv)
+            lsh_scr[...] = alpha * lsh_scr[...] + _shadow_rowsum(p, s_kv,
+                                                                 g_kv)
         blk_alive = blockmax > MASK_VALUE / 2
         r_scr[...] = alpha * r_scr[...] + jnp.where(
             blk_alive, jnp.exp(blockmax - m_sub), 0.0)
@@ -336,13 +411,7 @@ def _efta_kernel(
                         row=fault_row, col=fault_ref[F_COL], bit=fault_ref[F_BIT])
         acc_scr[...] = acc_new
         if ft:
-            g2 = v.shape[-1] // s_out
-            vc1 = jnp.zeros((v.shape[0], s_out), jnp.float32)
-            vc2 = jnp.zeros((v.shape[0], s_out), jnp.float32)
-            for l in range(g2):
-                seg = v[:, l * s_out:(l + 1) * s_out].astype(jnp.float32)
-                vc1 = vc1 + seg
-                vc2 = vc2 + float(l + 1) * seg
+            vc1, vc2 = _col_checksums(v, s_out)
             pf = p.astype(jnp.float32)
             oc1_scr[...] = alpha * oc1_scr[...] + jax.lax.dot_general(
                 pf, vc1, (((1,), (0,)), ((), ())),
@@ -353,9 +422,10 @@ def _efta_kernel(
             if not unified:
                 # Unoptimized EFTA: verify the output checksum at EVERY kv
                 # step (Tables 1-2 compare this against unified verification).
-                s1 = _fold_slices(acc_scr[...], s_out, weighted=False)
-                d1o = oc1_scr[...] - s1
-                det_scr[4] += (jnp.abs(d1o) > eps3).sum(dtype=jnp.int32)
+                n_bad, _ = _verify_output(acc_scr[...], oc1_scr[...],
+                                          oc2_scr[...], s_out=s_out,
+                                          eps3=eps3, correct=False)
+                det_scr[4] += n_bad
 
     # ---- finalize: SNVR on ℓ + unified output verification ---------------
     @pl.when(jk == n_kv - 1)
@@ -389,16 +459,11 @@ def _efta_kernel(
                 bound = vmax_scr[0] * 1.001 + 1e-6
                 o = jnp.where(jnp.isfinite(o) & (jnp.abs(o) <= bound),
                               o, 0.0)
-            oc1 = oc1_scr[...] / l_safe
-            oc2 = oc2_scr[...] / l_safe
-            s1 = _fold_slices(o, s_out, weighted=False)
-            s2 = _fold_slices(o, s_out, weighted=True)
-            d1 = oc1 - s1
-            d2 = oc2 - s2
-            bad = ~(jnp.abs(d1) <= eps3)   # NaN-safe (detect mode)
-            det_scr[4] += bad.sum(dtype=jnp.int32)
-            if correct:
-                o = _correct_strided(o, d1, d2, bad, s_out)
+            # NaN-safe in detect mode: a NaN delta is flagged
+            n_bad, o = _verify_output(o, oc1_scr[...] / l_safe,
+                                      oc2_scr[...] / l_safe, s_out=s_out,
+                                      eps3=eps3, correct=correct)
+            det_scr[4] += n_bad
         o_ref[...] = o.astype(o_ref.dtype)
         rep_ref[...] = _report_tile(jnp.zeros(rep_ref.shape, jnp.int32),
                                     [det_scr[i] for i in range(5)])
@@ -483,8 +548,8 @@ def efta_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),   # l shadow
             pltpu.VMEM((block_q, 1), jnp.float32),   # r (SNVR bound)
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
-            pltpu.VMEM((block_q, s_out), jnp.float32),  # O checksum 1
-            pltpu.VMEM((block_q, s_out), jnp.float32),  # O checksum 2
+            pltpu.VMEM((block_q, d), jnp.float32),   # O checksum 1
+            pltpu.VMEM((block_q, d), jnp.float32),   # O checksum 2
             pltpu.SMEM((5,), jnp.int32),             # detection counters
             pltpu.SMEM((1,), jnp.float32),           # running max|V| (NVR)
         ],

@@ -88,7 +88,7 @@ import numpy as np
 
 from repro.core import checksum as cks
 from repro.core.fault import FaultSpec, flip_bit_at
-from repro.kernels.efta_paged import paged_fault_descriptor
+from repro.kernels.efta_paged import pages_per_block, paged_fault_descriptor
 from repro.kernels.ops import gather_block_kv
 from repro.models.api import Model
 from repro.models.attention import KVCache, PagedKVCache, paged_rollback
@@ -1296,11 +1296,15 @@ class PagedServeEngine(ServeEngine):
 
         The step runs inside the profiler span ``serve.step`` (see
         :mod:`repro.serve.trace`); on the fused backend its parts have
-        spans of their own and ``serve.step`` carries the program ``width``,
-        the requests fed (``running``), the rows fed and the ``attempts``."""
-        with span("step") as step_span:
-            if self.kernel == "fused":
+        spans of their own and ``serve.step`` carries the paged kernel's
+        table slots a grid step (``kv_pages_per_block``), the program
+        ``width``, the requests fed (``running``), the rows fed and the
+        ``attempts``."""
+        if self.kernel == "fused":
+            with span("step", kv_pages_per_block=pages_per_block(
+                    self.block_size, self.max_blocks)) as step_span:
                 return self._step_unified(faults, step_span)
+        with span("step"):
             return self._step_gather(faults)
 
     def lower_step(self, chunk: int):

@@ -132,3 +132,68 @@ def test_kernel_unified_vs_stepwise():
                          unified=unified)
         out, _ = efta_attention_pallas(q, k, v, cfg=cfg, block_q=64)
         np.testing.assert_allclose(out, attention_ref(q, k, v), atol=2e-6)
+
+
+@pytest.mark.parametrize("stride,segments,pages", [
+    (4, 4, 8),      # the paged kernel's compute block at block 16
+    (16, 32, 1),    # the contiguous kernel's 512-key tile
+    (3, 12, 2),     # segment counts that are not powers of two
+    (8, 6, 3),
+    (2, 7, 2),
+    (4, 1, 5),
+])
+def test_fold_helpers_match_numpy(stride, segments, pages):
+    """The shared fold helpers on a tile of ``pages`` pages: each fold
+    index holds the sum, the (1..g)-weighted sum, the max and the product
+    over its page's segments; the spread copies a fold value to every
+    segment; and the correction puts a delta back into the segment it came
+    from, in every page at once."""
+    from jax.experimental import pallas as pl
+    from repro.kernels.efta_attention import (_correct, _fold,
+                                              _fold_index, _spread)
+
+    w = stride * segments * pages
+    rng = np.random.default_rng(stride * 100 + segments)
+    x = rng.standard_normal((8, w)).astype(np.float32)
+
+    def folds(x_ref, *outs):
+        t = x_ref[...]
+        for o, kw in zip(outs, (dict(), dict(weighted=True),
+                                dict(op=jnp.maximum), dict(op=jnp.multiply))):
+            o[...] = _fold(t, stride, segments, axis=1, **kw)
+        at = _fold_index(t.shape, stride, stride * segments)
+        outs[4][...] = _spread(jnp.where(at, t, 0.0), stride, segments)
+
+    got = pl.pallas_call(folds, out_shape=[jax.ShapeDtypeStruct(
+        x.shape, jnp.float32)] * 5, interpret=True)(x)
+    seg = x.reshape(8, pages, segments, stride).astype(np.float64)
+    weight = np.arange(1, segments + 1)[None, None, :, None]
+    for out, want in zip(got[:4], (seg.sum(2), (seg * weight).sum(2),
+                                   seg.max(2), seg.prod(2))):
+        np.testing.assert_allclose(
+            np.asarray(out).reshape(seg.shape)[:, :, 0], want, rtol=1e-5,
+            atol=1e-5)
+    np.testing.assert_array_equal(
+        np.asarray(got[4]).reshape(seg.shape),
+        np.broadcast_to(x.reshape(seg.shape)[:, :, :1], seg.shape))
+
+    # one corrupted element in every page of row 0, deltas from clean folds
+    y = x.copy()
+    for p in range(pages):
+        y[0, (p * segments + rng.integers(segments)) * stride
+          + rng.integers(stride)] += 5.0
+    clean = np.zeros((2,) + x.shape, np.float32)
+    clean[0].reshape(seg.shape)[:, :, 0] = seg.sum(2)
+    clean[1].reshape(seg.shape)[:, :, 0] = (seg * weight).sum(2)
+
+    def fix(y_ref, c1_ref, c2_ref, o_ref):
+        t = y_ref[...]
+        d1 = c1_ref[...] - _fold(t, stride, segments, axis=1)
+        d2 = c2_ref[...] - _fold(t, stride, segments, axis=1, weighted=True)
+        bad = (jnp.abs(d1) > 1e-3) & _fold_index(t.shape, stride,
+                                                  stride * segments)
+        o_ref[...] = _correct(t, d1, d2, bad, stride, segments)
+
+    fixed = pl.pallas_call(fix, out_shape=jax.ShapeDtypeStruct(
+        x.shape, jnp.float32), interpret=True)(y, clean[0], clean[1])
+    np.testing.assert_allclose(np.asarray(fixed), x, atol=1e-4)

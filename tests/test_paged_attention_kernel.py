@@ -12,6 +12,12 @@ is flagged at the exact (request, table-slot) it occupies by the in-loop
 verify (report-tile site 6, ``kv``); and in-compute SEUs at the five paper
 sites behave exactly as in the contiguous EFTA kernel (corrected in
 ``correct`` mode, flagged in ``detect`` mode).
+
+The kernel streams compute blocks of ``P = 128 // block_size`` table slots
+(capped at the table length); the parity matrices run tables of one block
+and of several, whose length ``P`` does not divide, and the fault tests
+hold the kernel's counts to the one-page schedule (``pages=1``) with SEUs on
+a page inside a block and on the last, partial block.
 """
 import sys
 
@@ -23,15 +29,17 @@ from _propcheck import given, settings, st  # noqa: E402
 
 
 def _make_case(seed, *, B, mb, bs, hkv, grp, hd, cs, fragment=True,
-               stale_scale=1.0):
-    """Random pool + fragmented tables + ragged lengths. Pool rows past each
-    request's valid prefix hold *stale* data (recycled-block model) scaled by
-    ``stale_scale`` — the kernel must mask them out, not read zeros."""
+               stale_scale=1.0, lens=None):
+    """Random pool + fragmented tables + ragged lengths (or ``lens``). Pool
+    rows past each request's valid prefix hold *stale* data (recycled-block
+    model) scaled by ``stale_scale`` — the kernel must mask them out, not
+    read zeros."""
     import jax.numpy as jnp
     from repro.core import checksum as cks
 
     rng = np.random.default_rng(seed)
-    per_req = [int(rng.integers(1, mb * bs + 1)) for _ in range(B)]
+    per_req = (list(lens) if lens is not None else
+               [int(rng.integers(1, mb * bs + 1)) for _ in range(B)])
     n_real = sum(-(-t // bs) for t in per_req)
     nb = n_real + 3                     # headroom: unmapped blocks stay stale
     ids = np.arange(1, nb + 1)
@@ -216,34 +224,43 @@ def test_detect_mode_flags_without_correcting():
     assert np.asarray(rep2.detected).sum() == 0
 
 
-@given(st.integers(0, 10_000), st.sampled_from([8, 16]),
-       st.sampled_from([(1, 1), (2, 1), (2, 2), (1, 4)]),
-       st.booleans())
-@settings(max_examples=6, deadline=None)
-def test_parity_property_ragged_gqa_fragmented(seed, bs, heads, fragment):
+@pytest.mark.parametrize("mb,examples", [(3, 6), (19, 3)],
+                         ids=["one-block", "table19"])
+def test_parity_property_ragged_gqa_fragmented(mb, examples):
     """Property sweep: random ragged lengths, permuted/fragmented tables,
-    MHA/GQA/MQA ratios, two block sizes — fused == gather+EFTA == reference,
-    zero detections. Loud stale rows past every valid prefix prove the
-    ragged masking reads nothing it shouldn't."""
-    import functools
-    import jax
-    from repro.core.efta import EFTAConfig
-    from repro.kernels.efta_paged import efta_paged_attention_pallas
+    MHA/GQA/MQA ratios, two block sizes (P = 16 and 8 pages a compute
+    block) and two table lengths — one compute block, and 19 slots, which
+    neither P divides, so lengths end inside a block and the table is
+    padded — fused == gather+EFTA == reference, zero detections. Loud stale
+    rows past every valid prefix prove the ragged masking reads nothing it
+    shouldn't. The 19-slot table, whose examples cost twice as much, takes
+    half as many."""
+    @given(st.integers(0, 10_000), st.sampled_from([8, 16]),
+           st.sampled_from([(1, 1), (2, 1), (2, 2), (1, 4)]),
+           st.booleans())
+    @settings(max_examples=examples, deadline=None)
+    def check(seed, bs, heads, fragment):
+        import functools
+        import jax
+        from repro.core.efta import EFTAConfig
+        from repro.kernels.efta_paged import efta_paged_attention_pallas
 
-    hkv, grp = heads
-    cfg = EFTAConfig(mode="correct", stride=8, block_kv=bs)
-    case = _make_case(seed, B=2, mb=3, bs=bs, hkv=hkv, grp=grp, hd=16,
-                      cs=min(8, bs), fragment=fragment, stale_scale=50.0)
-    q, pk, pv, kc, vc, bt, lens = case
-    rep = jax.jit(functools.partial(
-        efta_paged_attention_pallas, cfg=cfg, interpret=True))(
-        q, pk, pv, kc, vc, bt, lens)
-    efta_out, ref_out = _oracles(q, pk, pv, bt, lens, cfg=cfg)
-    got = np.asarray(rep.out)
-    np.testing.assert_allclose(got, efta_out, atol=2e-5, rtol=2e-5)
-    np.testing.assert_allclose(got, ref_out, atol=1e-4, rtol=1e-4)
-    assert np.asarray(rep.detected).sum() == 0
-    assert not np.asarray(rep.bad_blocks).any()
+        hkv, grp = heads
+        cfg = EFTAConfig(mode="correct", stride=8, block_kv=bs)
+        case = _make_case(seed, B=2, mb=mb, bs=bs, hkv=hkv, grp=grp, hd=16,
+                          cs=min(8, bs), fragment=fragment, stale_scale=50.0)
+        q, pk, pv, kc, vc, bt, lens = case
+        rep = jax.jit(functools.partial(
+            efta_paged_attention_pallas, cfg=cfg, interpret=True))(
+            q, pk, pv, kc, vc, bt, lens)
+        efta_out, ref_out = _oracles(q, pk, pv, bt, lens, cfg=cfg)
+        got = np.asarray(rep.out)
+        np.testing.assert_allclose(got, efta_out, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(got, ref_out, atol=1e-4, rtol=1e-4)
+        assert np.asarray(rep.detected).sum() == 0
+        assert not np.asarray(rep.bad_blocks).any()
+
+    check()
 
 
 def _chunk_oracle(q, pool_k, pool_v, bt, kv_lens, q_lens, *, cfg,
@@ -309,44 +326,83 @@ def test_chunked_q_matches_per_row_decode_oracle():
     assert not np.asarray(rep.bad_blocks).any()
 
 
-@given(st.integers(0, 10_000), st.sampled_from([8, 16]),
-       st.sampled_from([(1, 1), (2, 2), (1, 4)]),
-       st.sampled_from([3, 8, 13]))
-@settings(max_examples=6, deadline=None)
-def test_chunked_parity_property_matrix(seed, bs, heads, chunk):
-    """Property sweep of the unified kernel: chunk widths x block sizes x
-    MHA/GQA/MQA x ragged lengths x fragmented tables, chunk boundaries
-    landing mid-block — chunked == sequential single-token decode, zero
-    detections, loud stale rows never read."""
-    import functools
-    import jax
-    import jax.numpy as jnp
-    from repro.core.efta import EFTAConfig
-    from repro.kernels.efta_paged import efta_paged_attention_pallas
+def _chunk_reference(q, pool_k, pool_v, bt, kv_lens, q_lens, window=None):
+    """Float64 softmax attention of every valid chunk row over its own
+    causal (and windowed) prefix of the gathered table; zero elsewhere."""
+    B, H, C, hd = q.shape
+    hkv = pool_k.shape[1]
+    grp = H // hkv
+    out = np.zeros((B, H, C, hd))
+    for i in range(B):
+        k = np.asarray(pool_k, np.float64)[np.asarray(bt)[i]]
+        v = np.asarray(pool_v, np.float64)[np.asarray(bt)[i]]
+        k = k.transpose(1, 0, 2, 3).reshape(hkv, -1, hd)
+        v = v.transpose(1, 0, 2, 3).reshape(hkv, -1, hd)
+        base = int(kv_lens[i]) - int(q_lens[i])
+        for c in range(int(q_lens[i])):
+            pos = base + c
+            j = np.arange(pos + 1)
+            if window is not None:
+                j = j[pos - j < window]
+            for hq in range(H):
+                s = k[hq // grp, j] @ np.asarray(q, np.float64)[i, hq, c]
+                w = np.exp((s - s.max()) / np.sqrt(hd))
+                out[i, hq, c] = (w / w.sum()) @ v[hq // grp, j]
+    return out
 
-    hkv, grp = heads
-    cfg = EFTAConfig(mode="correct", stride=8, block_kv=bs)
-    _, pk, pv, kc, vc, bt, lens = _make_case(
-        seed, B=2, mb=3, bs=bs, hkv=hkv, grp=grp, hd=16, cs=min(8, bs),
-        stale_scale=50.0)
-    rng = np.random.default_rng(seed + 1)
-    lens_np = np.asarray(lens)
-    q_lens = np.minimum(
-        rng.integers(1, chunk + 1, size=lens_np.shape), lens_np
-    ).astype(np.int32)
-    q = jnp.asarray(rng.standard_normal(
-        (2, hkv * grp, chunk, 16)).astype(np.float32))
-    rep = jax.jit(functools.partial(
-        efta_paged_attention_pallas, cfg=cfg, interpret=True))(
-        q, pk, pv, kc, vc, bt, lens, jnp.asarray(q_lens))
-    got = np.asarray(rep.out)
-    oracle = _chunk_oracle(q, pk, pv, bt, lens_np, q_lens, cfg=cfg)
-    for i in range(2):
-        n = int(q_lens[i])
-        np.testing.assert_allclose(got[i, :, :n], oracle[i, :, :n],
-                                   atol=2e-5, rtol=2e-5)
-    assert np.asarray(rep.detected).sum() == 0
-    assert not np.asarray(rep.bad_blocks).any()
+
+@pytest.mark.parametrize("mb,chunks,examples", [(3, (3, 8, 13), 6),
+                                                (19, (1,), 3),
+                                                (19, (32,), 3)],
+                         ids=["one-block", "table19-chunk1",
+                              "table19-chunk32"])
+def test_chunked_parity_property_matrix(mb, chunks, examples):
+    """Property sweep of the unified kernel: chunk widths 1 to 32 x block
+    sizes (P = 16 and 8) x tables of one compute block and of 19 slots
+    (padded, lengths ending inside a block) x MHA/GQA/MQA x sliding windows
+    x ragged lengths x fragmented tables, chunk boundaries landing mid-block
+    — every valid row equals float64 softmax attention over its own
+    prefix, zero detections, loud stale rows never read. The 19-slot
+    tables, whose examples cost twice as much, take half as many."""
+    @given(st.integers(0, 10_000), st.sampled_from([8, 16]),
+           st.sampled_from([(1, 1), (2, 2), (1, 4)]),
+           st.sampled_from([None, 9, 40]))
+    @settings(max_examples=examples, deadline=None)
+    def check(seed, bs, heads, window):
+        import functools
+        import jax
+        import jax.numpy as jnp
+        from repro.core.efta import EFTAConfig
+        from repro.kernels.efta_paged import efta_paged_attention_pallas
+
+        hkv, grp = heads
+        chunk = chunks[seed % len(chunks)]
+        cfg = EFTAConfig(mode="correct", stride=8, block_kv=bs)
+        _, pk, pv, kc, vc, bt, lens = _make_case(
+            seed, B=2, mb=mb, bs=bs, hkv=hkv, grp=grp, hd=16, cs=min(8, bs),
+            stale_scale=50.0)
+        rng = np.random.default_rng(seed + 1)
+        lens_np = np.asarray(lens)
+        q_lens = np.minimum(
+            rng.integers(1, chunk + 1, size=lens_np.shape), lens_np
+        ).astype(np.int32)
+        q = jnp.asarray(rng.standard_normal(
+            (2, hkv * grp, chunk, 16)).astype(np.float32))
+        win = None if window is None else jnp.int32(window)
+        rep = jax.jit(functools.partial(
+            efta_paged_attention_pallas, cfg=cfg, interpret=True))(
+            q, pk, pv, kc, vc, bt, lens, jnp.asarray(q_lens), window=win)
+        got = np.asarray(rep.out)
+        ref = _chunk_reference(q, pk, pv, bt, lens_np, q_lens, window=window)
+        for i in range(2):
+            n = int(q_lens[i])
+            np.testing.assert_allclose(got[i, :, :n], ref[i, :, :n],
+                                       atol=2e-5, rtol=2e-5)
+            assert not got[i, :, n:].any()   # padding rows are exactly zero
+        assert np.asarray(rep.detected).sum() == 0
+        assert not np.asarray(rep.bad_blocks).any()
+
+    check()
 
 
 def test_chunked_sliding_window_and_idle_rows():
@@ -458,3 +514,173 @@ def test_sliding_window_masks_like_the_contiguous_path():
                                    np.asarray(o)[0, :, 0, :],
                                    atol=2e-5, rtol=2e-5)
     assert np.asarray(rep.detected).sum() == 0
+
+
+# ---- compute blocks against the one-page schedule --------------------------
+#
+# The oracle is the framework's pure-JAX EFTA (``repro.core.efta``) over the
+# gathered table with ``block_kv = block_size``: one page a KV block, so every
+# check of the one-page schedule, with the same strides and thresholds; and,
+# for resident flips, the gather path's ``verify_block`` of each page.
+
+BLOCK_LENS = (19 * 16 - 5, 11 * 16 + 3)   # slot 18 of 19, slot 11
+# page 5 of compute block 1; page 2 of block 2, the last and partial one
+IN_BLOCK, LAST_BLOCK = 13, 18
+
+
+@pytest.fixture(scope="module", params=[1, 32], ids=["chunk1", "chunk32"])
+def block_case(request):
+    """Two requests over a 19-slot table at block 16 (P = 8: compute blocks
+    of slots 0-7, 8-15 and 16-18 of a padded 24), GQA 2:1, chunk 1 or 32;
+    the kernel jitted at the derived P, and request 0's one-page oracle."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.efta import EFTAConfig, efta_attention
+    from repro.kernels.efta_paged import (efta_paged_attention_pallas,
+                                          pages_per_block)
+    from repro.kernels.ops import gather_block_kv
+
+    chunk = request.param
+    B, mb, bs, hkv, grp, hd, cs = 2, 19, 16, 2, 2, 16, 8
+    assert pages_per_block(bs, mb) == 8
+    cfg = EFTAConfig(mode="correct", stride=8, block_kv=bs)
+    _, pk, pv, kc, vc, bt, lens = _make_case(
+        21, B=B, mb=mb, bs=bs, hkv=hkv, grp=grp, hd=hd, cs=cs,
+        lens=BLOCK_LENS)
+    rng = np.random.default_rng(22)
+    q = jnp.asarray(rng.standard_normal((B, hkv * grp, chunk, hd))
+                    .astype(np.float32))
+    q_lens = jnp.asarray([chunk, max(chunk // 4, 1)], jnp.int32)
+    fused = jax.jit(lambda q, pk, pv, kc, vc, fault: (
+        efta_paged_attention_pallas(q, pk, pv, kc, vc, bt, lens, q_lens,
+                                    cfg=cfg, fault=fault, interpret=True)))
+    # request 0 feeds the whole chunk: rows at kv_len - chunk + c
+    _, kg = gather_block_kv(pk[None], bt[0])
+    _, vg = gather_block_kv(pv[None], bt[0])
+    n = int(lens[0])
+    one_page = jax.jit(lambda fault: efta_attention(
+        q[:1], kg, vg, cfg=cfg, causal=True, kv_len=n, q_offset=n - chunk,
+        fault=fault))
+    return dict(q=q, pk=pk, pv=pv, kc=kc, vc=vc, bt=bt, lens=lens, cs=cs,
+                chunk=chunk, grp=grp, q_lens=np.asarray(q_lens),
+                fused=fused, one_page=one_page)
+
+
+@pytest.mark.parametrize("slot", [IN_BLOCK, LAST_BLOCK],
+                         ids=["page5-of-block1", "last-partial-block"])
+@pytest.mark.parametrize("site", ["GEMM1", "EXP", "ROWMAX", "ROWSUM",
+                                  "GEMM2"])
+def test_compute_seus_counted_as_by_the_one_page_schedule(block_case, site,
+                                                          slot):
+    """An SEU on a page inside a compute block, or on the last, partial
+    block: the kernel detects (and, in correct mode, repairs) it as the
+    one-page schedule does — the same counts at every site, the strike
+    counted, nothing on the other request, an empty bad plane — and its
+    output is the oracle's faulted, corrected one."""
+    import jax.numpy as jnp
+    from repro.core.fault import FaultSpec, Site
+
+    c = block_case
+    chunk, grp = c["chunk"], c["grp"]
+    row = min(2, chunk - 1)
+    # kernel: [site, table_block, b, kv_head 1, tile row (group row 1,
+    # chunk row `row`), col, bit, on]; oracle: query head 1 * grp + 1
+    desc = jnp.asarray([int(Site[site]), slot, 0, 1, chunk + row, 3, 27, 1],
+                       jnp.int32)
+    got = c["fused"](c["q"], c["pk"], c["pv"], c["kc"], c["vc"], desc)
+    o_ref, rep_ref = c["one_page"](FaultSpec.single(
+        Site[site], block=slot, head=grp + 1, row=row, col=3, bit=27))
+    det, det_ref = np.asarray(got.detected), np.asarray(rep_ref.detected)
+    np.testing.assert_array_equal(det[0, :5], det_ref)
+    np.testing.assert_array_equal(np.asarray(rep_ref.corrected), det_ref)
+    report_index = ["GEMM1", "EXP", "ROWMAX", "ROWSUM", "GEMM2"].index(site)
+    assert det_ref[report_index] >= 1, "the strike was not counted"
+    assert det[0, 5] == 0 and not det[1].any()
+    assert not np.asarray(got.bad_blocks).any()
+    np.testing.assert_allclose(np.asarray(got.out)[0],
+                               np.asarray(o_ref)[0], atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("slot", [IN_BLOCK, LAST_BLOCK],
+                         ids=["page5-of-block1", "last-partial-block"])
+@pytest.mark.parametrize("into", ["k", "v"])
+def test_resident_flip_sets_exactly_its_slot(block_case, into, slot):
+    """A resident K or V flip on a page inside a compute block, or on the
+    last, partial one: the bad plane holds exactly that (request, table
+    slot), and site 6 counts what the gather path's ``verify_block`` of
+    each live page of each head flags."""
+    import jax.numpy as jnp
+    from repro.core import checksum as cks
+    from repro.core.fault import flip_bit_at
+
+    c = block_case
+    pk, pv = c["pk"], c["pv"]
+    hkv, bs, hd = pk.shape[1], pk.shape[2], pk.shape[3]
+    bt, lens = np.asarray(c["bt"]), np.asarray(c["lens"])
+    blk = int(bt[0, slot])
+    flat = ((blk * hkv + 1) * bs + 3) * hd + 5     # head 1, row 3, col 5
+    hit = flip_bit_at(pk if into == "k" else pv, jnp.int32(flat),
+                      jnp.int32(28))
+    pk, pv = (hit, pv) if into == "k" else (pk, hit)
+    got = c["fused"](c["q"], pk, pv, c["kc"], c["vc"],
+                     jnp.zeros((8,), jnp.int32))
+    thr = cks.kv_block_threshold(pk.dtype)
+    flagged = np.zeros(bt.shape + (hkv,), bool)
+    for b in range(bt.shape[0]):
+        live = -(-int(lens[b]) // bs)
+        for x, checks in ((pk, c["kc"]), (pv, c["vc"])):
+            ids = bt[b, :live]
+            bad, _ = cks.verify_block(
+                x[ids], cks.Checksums(checks.c1[ids], checks.c2[ids]),
+                c["cs"], threshold=thr)
+            flagged[b, :live] |= np.asarray(bad)
+    assert np.argwhere(flagged.any(-1)).tolist() == [[0, slot]]
+    np.testing.assert_array_equal(np.asarray(got.bad_blocks),
+                                  flagged.any(-1))
+    det = np.asarray(got.detected)
+    np.testing.assert_array_equal(det[:, 5], flagged.sum(axis=(1, 2)))
+
+
+@given(st.integers(0, 10_000), st.sampled_from([4, 8, 16, 32]),
+       st.sampled_from([None, 5, 40, 300]))
+@settings(max_examples=20, deadline=None)
+def test_only_live_pages_are_copied(seed, bs, window):
+    """The live slots are the one-page kernel's: slot j runs when
+    ``j * bs < kv_len`` and its last key is inside the earliest row's
+    window. Walking the grid in order, the pool row a compute block's slot
+    names changes only to a live page of that slot — the pipeline, which
+    copies a block when its index changes, copies no other page — and names
+    every live page when its block runs."""
+    import jax.numpy as jnp
+    from repro.kernels.efta_paged import (NO_WINDOW, _live_slots,
+                                          _source_pages, pages_per_block)
+
+    rng = np.random.default_rng(seed)
+    B, mb = 5, 21
+    pages = pages_per_block(bs, mb)
+    n_pad = -(-mb // pages) * pages
+    kv = rng.integers(0, mb * bs + 1, size=B).astype(np.int32)
+    ql = np.minimum(rng.integers(0, 3 * bs, size=B), kv).astype(np.int32)
+    bt = np.zeros((B, n_pad), np.int32)
+    bt[:, :mb] = rng.permutation(np.arange(1, B * mb + 1)).reshape(B, mb)
+    win = NO_WINDOW if window is None else window
+    lo, hi = _live_slots(jnp.asarray(kv), jnp.asarray(ql), jnp.int32(win),
+                         block_size=bs)
+    src = np.asarray(_source_pages(jnp.asarray(bt), lo, hi, pages))
+    lo, hi = np.asarray(lo), np.asarray(hi)
+    prev = [None] * pages
+    for b in range(B):
+        j = np.arange(n_pad)
+        runs = (j * bs < kv[b]) & (kv[b] - ql[b] - (j * bs + bs - 1) < win)
+        np.testing.assert_array_equal(runs, (j >= lo[b]) & (j < hi[b]))
+        live_rows = {p: {int(bt[b, x]) for x in j[runs & (j % pages == p)]}
+                     for p in range(pages)}
+        for i in range(n_pad // pages):
+            for p in range(pages):
+                row = int(src[b, i * pages + p])
+                if runs[i * pages + p]:
+                    assert row == bt[b, i * pages + p]
+                if row != prev[p]:
+                    assert row in live_rows[p] or (
+                        not live_rows[p] and row == 0), (b, i, p, row)
+                prev[p] = row

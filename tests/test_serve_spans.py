@@ -15,6 +15,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.kernels.efta_paged import pages_per_block
 from repro.serve import paged
 
 PROMPT = 20          # under the chunk width: one chunk prefills a prompt
@@ -122,8 +123,9 @@ def test_step_span_arguments(served):
     ran = [args for args, inner in clean if "dispatch" in inner]
     # both prompts prefill in the first step at the chunk width; every
     # later step only decodes, at width 1
-    assert ran[0] == {"width": eng.chunk_size, "running": 2,
-                      "rows": 2 * PROMPT, "attempts": 1}
+    assert ran[0] == {"kv_pages_per_block": pages_per_block(
+        eng.block_size, eng.max_blocks), "width": eng.chunk_size,
+        "running": 2, "rows": 2 * PROMPT, "attempts": 1}
     assert len(ran) >= 3
     for args in ran[1:]:
         assert args["width"] == 1 and args["attempts"] == 1
@@ -160,6 +162,8 @@ def test_spans_land_in_the_profiler_trace(tiny, tmp_path):
             "serve.dispatch", "serve.wait", "serve.commit"} <= names
     steps = [dict(ev.stats) for ev in events if ev.name == "serve.step"]
     assert steps and steps[0]["width"] == 1 and steps[0]["running"] == 1
+    assert steps[0]["kv_pages_per_block"] == pages_per_block(
+        eng.block_size, eng.max_blocks)
 
 
 def test_step_program_names_its_scopes(tiny):

@@ -32,9 +32,12 @@ def one_chip():
 
 
 def _assert_kernel_named(hlo: str, name: str) -> None:
-    """The kernel's custom call carries ``name`` as a component of its
-    ``op_name`` metadata, where a trace reader finds it."""
-    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    """The program holds exactly one kernel custom call, and it carries
+    ``name`` as a component of its ``op_name`` metadata, where a trace
+    reader finds it."""
+    calls = [line for line in hlo.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert len(calls) == 1, calls
     paths = [m for line in calls
              for m in re.findall(r'op_name="([^"]+)"', line)]
     assert paths and all(name in p.split("/") for p in paths), paths
@@ -45,19 +48,30 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("heads,kv_heads,chunk", [
-    (12, 12, 1),     # gpt2 decode step program (chunk width 1)
-    (12, 12, 32),    # gpt2 chunked-prefill program (chunk = 2 * block)
-    (16, 4, 32),     # GQA: 4 query heads per KV head folded into the rows
-], ids=["gpt2-chunk1", "gpt2-chunk32", "gqa4-chunk32"])
-def test_paged_kernel_compiles_for_v5e(one_chip, heads, kv_heads, chunk):
+@pytest.mark.parametrize("heads,kv_heads,chunk,dtype", [
+    (12, 12, 1, "bfloat16"),   # gpt2 decode step program (chunk width 1)
+    (12, 12, 32, "bfloat16"),  # gpt2 chunked-prefill program (2 * block)
+    (16, 4, 32, "bfloat16"),   # GQA: 4 query heads per KV head in the rows
+    (12, 12, 32, "float32"),   # the gpt2-f32 cell's chunk-width program
+    (25, 25, 32, "bfloat16"),  # gpt2-xl's chunk-width program: 25 KV heads
+    (25, 25, 1, "bfloat16"),   # gpt2-xl's width-1 program
+], ids=["gpt2-chunk1", "gpt2-chunk32", "gqa4-chunk32", "gpt2-f32-chunk32",
+        "gpt2-xl-chunk32", "gpt2-xl-chunk1"])
+def test_paged_kernel_compiles_for_v5e(one_chip, heads, kv_heads, chunk,
+                                       dtype):
+    """Head_dim 64, block 16, a 64-slot table: compute blocks of 8 pages
+    (128 keys), so the multi-page kernel is what Mosaic compiles. Every
+    page block and piece of state holds all KV heads, so gpt2-xl's 25 is
+    the most scoped VMEM a served model asks of it."""
     import jax
     import jax.numpy as jnp
     from repro.core import checksum as cks
     from repro.core.efta import EFTAConfig
-    from repro.kernels.efta_paged import efta_paged_attention_pallas
+    from repro.kernels.efta_paged import (efta_paged_attention_pallas,
+                                          pages_per_block)
 
     b, d, bs, mb, cs, nblocks = 8, 64, 16, 64, 8, 128
+    assert pages_per_block(bs, mb) == 8
     cfg = EFTAConfig(mode="correct")
 
     def step(q, k, v, kc1, kc2, vc1, vc2, bt, kv_lens, q_lens, fault):
@@ -66,10 +80,10 @@ def test_paged_kernel_compiles_for_v5e(one_chip, heads, kv_heads, chunk):
             kv_lens, q_lens, cfg=cfg, fault=fault, interpret=False)
         return rep.out, rep.detected, rep.bad_blocks
 
-    bf16 = jnp.bfloat16
-    pool = _spec(one_chip, (nblocks + 1, kv_heads, bs, d), bf16)
-    checks = _spec(one_chip, (nblocks + 1, kv_heads, cs, d), bf16)
-    args = [_spec(one_chip, (b, heads, chunk, d), bf16), pool, pool,
+    dt = jnp.dtype(dtype)
+    pool = _spec(one_chip, (nblocks + 1, kv_heads, bs, d), dt)
+    checks = _spec(one_chip, (nblocks + 1, kv_heads, cs, d), dt)
+    args = [_spec(one_chip, (b, heads, chunk, d), dt), pool, pool,
             checks, checks, checks, checks,
             _spec(one_chip, (b, mb), jnp.int32),
             _spec(one_chip, (b,), jnp.int32), _spec(one_chip, (b,), jnp.int32),
