@@ -4,6 +4,7 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import types
 from pathlib import Path
 from typing import Callable, Optional
 
@@ -35,6 +36,7 @@ class Cell:
     name: str
     chips: int
     config: dict                    # bench/configs/<config>.json
+    arch: types.ModuleType          # bench/arch/<model_type>.py
     traffic: dict                   # bench/traffic/<traffic>.json
     generator: Callable             # its "generator": a Stream class
     limits: dict                    # bench/cells/<name>.json "limits"
@@ -63,6 +65,13 @@ def load_generator(name: str, bench_dir: Path = BENCH_DIR) -> Callable:
     """The request generator ``name``: ``bench/generators/<name>.py``, whose
     ``Stream(traffic, seed, vocab=, max_total=)`` yields the requests."""
     return load_module("generators", name, bench_dir).Stream
+
+
+def load_arch(model_type: str, bench_dir: Path = BENCH_DIR):
+    """The architecture module ``model_type``: ``bench/arch/<model_type>.py``
+    (the configuration file's ``model_type``), which holds all the harness
+    knows of one architecture family (see ``bench/arch/gpt2.py``)."""
+    return load_module("arch", model_type, bench_dir)
 
 
 def _metrics(entries, *, end_to_end: bool, bench_dir: Path):
@@ -100,8 +109,9 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     per_layer = [m for m in _metrics(bench["per_layer"], end_to_end=False,
                                      bench_dir=bench_dir)
                  if _reports(m, name, e2e_names)]
-    return Cell(name=name, chips=int(w["chips"]),
-                config=load_json(root / cfg_entry["file"]),
+    config = load_json(root / cfg_entry["file"])
+    return Cell(name=name, chips=int(w["chips"]), config=config,
+                arch=load_arch(config["model_type"], bench_dir),
                 traffic=traffic,
                 generator=load_generator(traffic["generator"], bench_dir),
                 limits=cell_file["limits"], sample=cell_file["sample"],

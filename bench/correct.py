@@ -59,11 +59,13 @@ def sample(records: list, spec: dict, seed: int) -> list:
     return out
 
 
-def served_gaps(weights: dict, cfg: dict, records: list, *, pad_to: int,
+def served_gaps(logits, weights: dict, records: list, *, pad_to: int,
                 low: Optional[str] = None):
     """Per sampled request, the reference gap of every served token, and
     with ``low`` the gap of the token the lower precision puts first at
-    every position of the prompt and the served tokens."""
+    every position of the prompt and the served tokens. ``logits(w, seq,
+    low=)`` is the architecture's reference forward for the cell's
+    configuration."""
     import jax.numpy as jnp
 
     from bench import reference
@@ -75,8 +77,8 @@ def served_gaps(weights: dict, cfg: dict, records: list, *, pad_to: int,
         n = r.prompt_len + len(gen)
         seq[:r.prompt_len] = np.asarray(r.req.prompt, np.int32)
         seq[r.prompt_len:n] = gen
-        s, c = reference.gaps(weights, jnp.asarray(seq), n_head=cfg["n_head"],
-                              eps=cfg["layer_norm_epsilon"], low=low)
+        s, c = reference.gaps(weights, jnp.asarray(seq), logits=logits,
+                              low=low)
         served.append(np.asarray(s)[r.prompt_len - 1:n - 1])
         if low is not None:
             control.append(np.asarray(c)[:n - 1])

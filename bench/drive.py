@@ -22,7 +22,7 @@ import math
 import time
 from typing import Any, Callable, List, Optional
 
-from bench import system, work
+from bench import system
 
 Annotate = Callable[[str], Any]
 
@@ -51,7 +51,6 @@ class Step:
     t1: float
     running: int                   # requests that took part in the step
     rows: int                      # rows fed, all requests
-    pairs: int                     # visible query-key pairs, all requests
     live: list                     # (rows, resident tokens) per request
     width1: bool                   # no request fed more than one row, so
     #                                the engine ran its width-1 program
@@ -142,7 +141,7 @@ class Driver:
     def _observe(self, before, t0: float, t1: float) -> None:
         bs = self.geometry["block_size"]
         chunk = self.geometry["chunk_size"]
-        live, rows_all, pairs_all, running = [], 0, 0, 0
+        live, rows_all, running = [], 0, 0
         still, made = [], []
         for rec, gen0, had_slot in before:
             req = rec.req
@@ -164,7 +163,6 @@ class Driver:
                 rows, kv_len = 1, rec.prompt_len + gen0
             live.append((rows, kv_len))
             rows_all += rows
-            pairs_all += work.visible_pairs(rows, kv_len)
             rec.token_t.extend([t1] * new)
             made.append((rec, new))
             if new and rec.first_t is None:
@@ -183,7 +181,7 @@ class Driver:
             for rec, new in made:
                 rec.width1_tokens += new
         self.steps.append(Step(t0=t0, t1=t1, running=running, rows=rows_all,
-                               pairs=pairs_all, live=live, width1=width1))
+                               live=live, width1=width1))
 
 
 def warm_programs(eng, chunk: int, vocab: int) -> None:

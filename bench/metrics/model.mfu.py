@@ -1,7 +1,9 @@
 """Model step: useful FLOPs of the window over the chip's peak for the
 traced window. Useful FLOPs are each fed row's matrix products (2 per
-weight, LM head included) and its attention over the tokens it can see;
-padding rows, idle slots and the grid the kernel walks do not count."""
+weight a token passes, LM head included: the architecture module's
+``matmul_flops_per_token``) and, in every layer, its attention over the
+tokens it can see there; padding rows, idle slots and the grid the kernel
+walks do not count."""
 from bench import work
 
 
@@ -9,7 +11,7 @@ def read(run):
     if run.trace is None or not run.exact or not run.steps:
         return None
     rows = sum(s.rows for s in run.steps)
-    pairs = sum(s.pairs for s in run.steps)
-    flops = rows * work.matmul_flops_per_token(run.cfg) \
-        + work.attention_flops(run.cfg, pairs)
+    flops = rows * run.arch.matmul_flops_per_token(run.cfg) \
+        + work.attention_flops(run.layers,
+                               [p for s in run.steps for p in s.live])
     return 100.0 * flops / (run.trace.window_s * run.peaks["flops_per_s"])

@@ -7,10 +7,13 @@ Set-up (counted in ``setup_s``): make the weights on the device from the
 seed, build the engine, compile (or load from the compile cache) the step's
 two programs, then run the cell's traffic until it is steady. The window
 then runs for ``--seconds``. With ``--trace 1`` a profiler trace of the
-window gives the per-layer metrics; with ``--trace 0`` the end-to-end ones.
-After the window (and a drain that waits for the first token of every
-request due in it), the engine is freed and a sample of the finished
-requests is checked against the float32 reference.
+window gives the per-layer metrics, with the program's ``serve.*`` spans in
+it and the program scope of each op, read after the window from the step
+programs' compiled text (:mod:`bench.spans`); with ``--trace 0`` the
+end-to-end ones. After the window (and a drain that waits for the first
+token of every request due in it), the engine is freed and a sample of the
+finished requests is checked against the float32 reference of the
+configuration's architecture (``bench/arch/<model_type>.py``).
 
 Exits 2 outside a checkout, 3 when JAX finds no TPU or fewer chips than
 the cell asks for, and prints no result then.
@@ -133,21 +136,22 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, device: dict,
     """Set up, measure and check one cell; returns the result object.
     ``control`` (``bench/calibrate.py`` only) also reads, on the same
     sample, the widest gap of the reference computed in that precision;
-    ``observe`` (``bench/sweep.py`` only) receives the requests' records."""
+    ``observe`` receives the requests' records and, traced, the trace's
+    ``Summary``."""
     import jax
 
-    from bench import correct, drive, reference, system, tracing
+    from bench import correct, drive, reference, spans, system, tracing
     from bench import weights as weights_mod
 
-    cfg, eng_cfg = cell.config, cell.config["engine"]
+    cfg, eng_cfg, arch = cell.config, cell.config["engine"], cell.arch
     # the configuration's matrix-product precision, for every product the
     # program traces (the kernel's included); unset: JAX's default
     jax.config.update("jax_default_matmul_precision",
                       cfg.get("matmul_precision"))
     counter = CompileCounter()
-    mcfg = system.model_config(cfg)
-    w = weights_mod.make(cfg, seed)
-    params = system.program_params(w)
+    mcfg = arch.model_config(cfg)
+    w = weights_mod.make(arch, cfg, seed)
+    params = arch.program_params(w)
     system.check_params(mcfg, params)
     jax.block_until_ready(w)
     eng = system.build_engine(mcfg, params, eng_cfg)
@@ -206,15 +210,24 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, device: dict,
     failed = sum(r.first_t is None for r in due)
     memory_peak = _peak_bytes(cell.chips)
 
-    summary = None
+    summary, read_s = None, {}
     if trace:
-        summary = tracing.reduce(tracing.capture_dir_trace(log_dir).planes)
+        t = clock()
+        planes = list(tracing.capture_dir_trace(log_dir).planes)
         shutil.rmtree(log_dir, ignore_errors=True)
+        t_texts = clock()
+        # a name both step programs use takes the chunk-width one's scope
+        texts = [eng.lower_step(width).compile().as_text()
+                 for width in (1, eng.chunk_size)]
+        read_s["step_texts_s"] = clock() - t_texts
+        summary = spans.summarize(planes, texts)
+        read_s["trace_read_s"] = clock() - t
 
     steps = [s for s in drv.steps[:n_window_steps]
              if t_open < s.t1 <= t_close]
     run = types.SimpleNamespace(
-        cell=cell, cfg=cfg, geometry=geometry, peaks=peaks, seed=seed,
+        cell=cell, cfg=cfg, arch=arch, layers=arch.layers(cfg),
+        geometry=geometry, peaks=peaks, seed=seed,
         records=drv.records, steps=steps, exact=drv.exact, t_open=t_open,
         t_close=t_close, setup_s=setup_s, trace=summary)
     kind = "per_layer" if trace else "end_to_end"
@@ -239,9 +252,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, device: dict,
     del eng, drv.eng, params
     gc.unfreeze()
     gc.collect()
-    served, ctrl = correct.served_gaps(w, cfg, sampled,
-                                       pad_to=geometry["cache_len"],
-                                       low=control)
+    served, ctrl = correct.served_gaps(
+        reference.forward(arch, cfg), w, sampled,
+        pad_to=geometry["cache_len"], low=control)
     tokens = int(sum(len(g) for g in served))
     detections = sum(counts["detected"].values()) + counts[
         "kv_detected_blocks"]
@@ -252,8 +265,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, device: dict,
                               unserved=failed, detections=detected,
                               retries=retries)
     checks = verdict(served, detections, counts["retries"])
-    sigma = reference.bf16_logit_sigma(
-        cfg, float(w["wte"].astype("float32").std()))
+    sigma = reference.bf16_logit_sigma(len(run.layers), arch.lm_head(w))
     info = {"window_s": t_close - t_open, "steps": len(steps),
             "requests_finished": len(finished),
             "sampled_requests": len(sampled),
@@ -271,6 +283,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, device: dict,
             "work_counts_exact": drv.exact,
             "generator_late_p99_ms": _late_p99_ms(drv.records),
             "bf16_logit_sigma": sigma,
+            **read_s,
             **{f"ft.{k}": v for k, v in counts.items()}, **notes}
     if control is not None:
         # the control stands in the program's place: the same checks, its
@@ -280,7 +293,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, device: dict,
         info["control_correct"] = all(c.ok for c in ctrl_checks)
     if observe is not None:
         observe.update(records=drv.records, t_open=t_open, t_close=t_close,
-                       waiting_at_close=waiting_at_close)
+                       waiting_at_close=waiting_at_close, summary=summary)
     result = {"correct": all(c.ok for c in checks),
               "attempted": len(due), "failed": int(failed),
               "metrics": metrics,

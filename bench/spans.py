@@ -28,21 +28,15 @@ A scope is matched as a whole component of the ``op_name`` path, never as
 a substring of an op's text, which names operands such as
 ``%kv_append.12``.
 
-The harness keeps neither on its ``Summary`` yet, so
-``engine.host_gap_ms`` and ``model.kv_write_ms`` are not in
-``BENCHMARK.json``. Run from the root of a checkout, on the chip,
-
-    python3 -m bench.spans --workload <cell> --seed <n> --seconds <s>
-
-makes the same run as ``bench/run.py --trace 1`` with both metrics and the
-scope of each of the ten ops of most device time in its result line.
+:func:`summarize` builds the whole of a traced run's reading in one
+place: the :class:`bench.tracing.Summary` of the planes with the spans and
+the scopes beside it (a :class:`Trace`), where ``engine.host_gap_ms`` and
+``model.kv_write_ms`` read them.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-import sys
-from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
 from bench import tracing
@@ -54,8 +48,6 @@ UNSCOPED = "unscoped"
 # the program's named scopes (models/attention.py, models/transformer.py,
 # serve/paged.py) and the paged kernel's pallas_call name
 SCOPES = ("kv_guard", "kv_append", "efta_paged", "lm_head", "sample")
-# metrics that read what this module adds to a Summary
-METRICS = ("engine.host_gap_ms", "model.kv_write_ms")
 
 
 @dataclasses.dataclass
@@ -222,69 +214,20 @@ def scope_seconds(summary, scopes: Dict[str, str]) -> Dict[str, float]:
     return out
 
 
-def traced_run(cell, seed: int, seconds: float, **kw):
-    """``bench.run.run_cell`` traced, with :data:`METRICS` added to the
-    cell: the step's two programs are compiled once more when the engine
-    is built, for their HLO text, and the trace's ``Summary`` is given
-    ``spans`` (:func:`reduce` of its planes) and ``scopes`` (the chunk-width
-    program's :func:`op_scopes` over the width-1 program's: the trace keys
-    ops by instruction name alone, and a name both programs use takes the
-    chunk-width program's scope). Returns the result and the ``Summary``."""
-    from bench import catalog, run, system
-
-    texts, kept = [], {}
-    build, reduce_ = system.build_engine, tracing.reduce
-
-    def build_engine(*a, **k):
-        eng = build(*a, **k)
-        texts.extend(eng.lower_step(w).compile().as_text()
-                     for w in (1, eng.chunk_size))
-        return eng
-
-    def reduce_all(planes):
-        planes = list(planes)
-        summary = kept["summary"] = reduce_(planes)
-        summary.spans = reduce(planes)
-        summary.scopes = {k: v for text in texts
-                          for k, v in op_scopes(text).items()}
-        return summary
-
-    extra = tuple(catalog.Metric(
-        name=n, unit="ms", end_to_end=False, moves="itl_p95_ms",
-        workloads=None, read=catalog.load_reader(n)) for n in METRICS)
-    cell = dataclasses.replace(cell, per_layer=cell.per_layer + extra)
-    system.build_engine, tracing.reduce = build_engine, reduce_all
-    try:
-        result = run.run_cell(cell, seed, seconds, True, **kw)
-    finally:
-        system.build_engine, tracing.reduce = build, reduce_
-    summary = kept["summary"]
-    top = sorted(summary.op_seconds.items(), key=lambda kv: -kv[1])[:10]
-    result["op_scopes"] = [[k, summary.scopes.get(k, UNSCOPED)]
-                           for k, _ in top]
-    return result, summary
+@dataclasses.dataclass
+class Trace(tracing.Summary):
+    """A traced run's reading: the trace's :class:`bench.tracing.Summary`,
+    the window's ``serve.*`` spans, and each instruction's program scope."""
+    spans: Spans
+    scopes: Dict[str, str]
 
 
-def main(argv=None) -> int:
-    root = Path(__file__).resolve().parents[1]
-    sys.path.insert(0, str(root / "src"))
-    import json
-
-    from bench import catalog, run
-
-    args = run.parse_args(argv)
-    cell = catalog.load_cell(args.workload, root)
-    run.enable_compile_cache()
-    try:
-        device = run.device_info(cell.chips)
-    except RuntimeError as e:
-        run.say(f"bench: {e}")
-        return 3
-    result, _ = traced_run(cell, args.seed, args.seconds, device=device,
-                           peaks=catalog.peaks(device["kind"]))
-    print(json.dumps(result), flush=True)
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())
+def summarize(planes, hlo_texts) -> Trace:
+    """The :class:`Trace` of one window's ``planes`` and the compiled text
+    of each step program. The trace keys ops by instruction name alone: a
+    name that several programs use takes the scope of the last of
+    ``hlo_texts``."""
+    planes = list(planes)
+    return Trace(**vars(tracing.reduce(planes)), spans=reduce(planes),
+                 scopes={k: v for text in hlo_texts
+                         for k, v in op_scopes(text).items()})

@@ -1,5 +1,6 @@
 """The program-span reduction and its metric readers on a small recorded
 trace with known answers."""
+import dataclasses
 import time
 import types
 
@@ -121,10 +122,20 @@ def _run(trace, steps=2):
     return types.SimpleNamespace(trace=trace, steps=[object()] * steps)
 
 
+def test_summarize_keeps_the_summary_the_spans_and_the_scopes():
+    got = spans.summarize(_trace(), [_hlo(scoped=False), _hlo()])
+    assert isinstance(got, tracing.Summary)
+    assert tracing.Summary(**{f.name: getattr(got, f.name) for f in
+                              dataclasses.fields(tracing.Summary)}) \
+        == tracing.reduce(_trace())
+    assert got.spans == spans.reduce(_trace())
+    # a name both programs use takes the last program's scope
+    assert got.scopes == spans.op_scopes(_hlo())
+
+
 def test_kv_write_reads_the_scoped_ops():
     read = catalog.load_reader("model.kv_write_ms")
-    trace = tracing.reduce(_trace())
-    trace.scopes = spans.op_scopes(_hlo())
+    trace = spans.summarize(_trace(), [_hlo()])
     got = read(_run(trace))
     # (40 + 20) us over two steps
     assert got["value"] == pytest.approx(0.03)
@@ -132,13 +143,11 @@ def test_kv_write_reads_the_scoped_ops():
     assert got["efta_paged_ms"] == pytest.approx(0.2)
     assert got["unscoped_share"] == pytest.approx(100 * 20 / 530)
     # a program without the scopes reads nothing
-    trace.scopes = spans.op_scopes(_hlo(scoped=False))
-    assert read(_run(trace)) is None
+    assert read(_run(spans.summarize(_trace(), [_hlo(scoped=False)]))) is None
 
 
 def test_host_gap_reads_the_span_split():
-    trace = tracing.reduce(_trace())
-    trace.spans = spans.reduce(_trace())
+    trace = spans.summarize(_trace(), [_hlo()])
     read = catalog.load_reader("engine.host_gap_ms")
     got = read(_run(trace))
     # 470 us idle, 120 of them outside every serve span, over two steps
@@ -160,16 +169,22 @@ def test_new_readers_read_nothing_without_a_trace(name):
 def test_traced_run_gives_the_summary_spans_and_scopes():
     # on the CPU the trace has no device plane: the spans and the scopes
     # are there, and the metrics that need device ops read nothing
+    from bench import run
     from bench.tests import tiny
-    result, summary = spans.traced_run(
-        tiny.cell(), 12, 1.5, device={"platform": "cpu", "kind": "cpu",
-                                      "count": 1},
+    seen = {}
+    result = run.run_cell(
+        tiny.cell(), 12, 1.5, True, device={"platform": "cpu", "kind": "cpu",
+                                            "count": 1},
         peaks={"flops_per_s": 1e12, "hbm_bytes_per_s": 1e11},
-        t_start=time.perf_counter())
+        t_start=time.perf_counter(), observe=seen)
+    summary = seen["summary"]
     assert result["correct"]
     widths = {s.args["width"] for s in summary.spans.steps
               if "width" in s.args}
     assert widths and widths <= {1, tiny.CONFIG["engine"]["chunk_size"]}
     assert {"kv_guard", "kv_append", "lm_head", "sample"} <= set(
         summary.scopes.values())
-    assert not set(spans.METRICS) & set(result["metrics"])
+    names = {m.name for m in tiny.cell().per_layer}
+    assert {"engine.host_gap_ms", "model.kv_write_ms"} <= names
+    assert not names & {"engine.host_gap_ms", "model.kv_write_ms"} & set(
+        result["metrics"])

@@ -41,6 +41,7 @@ def cell() -> catalog.Cell:
 
     return catalog.Cell(
         name="tiny", chips=1, config=CONFIG,
+        arch=catalog.load_arch(CONFIG["model_type"]),
         traffic=TRAFFIC, generator=catalog.load_generator("lognormal"),
         limits=LIMITS, sample=SAMPLE,
         end_to_end=metrics(bench["end_to_end"], True),

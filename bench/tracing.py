@@ -23,6 +23,7 @@ import bisect
 import dataclasses
 import glob
 import os
+import re
 from typing import Dict, Iterable, List, Tuple
 
 WINDOW_SPAN = "bench.window"
@@ -36,12 +37,15 @@ class Summary:
     busy_s: float                      # averaged over the devices used
     devices: int
     op_seconds: Dict[str, float]       # self time, summed over devices
-    op_text: Dict[str, str]            # key -> the event's text and stats
     gaps: List[Tuple[float, str]]      # (seconds, host span), longest first
 
-    def seconds_matching(self, needle: str) -> float:
+    def seconds_named(self, name: str) -> float:
+        """Self seconds of the ops whose HLO instruction is ``name`` or
+        ``name.<n>``: a Pallas kernel's, whose ``pallas_call`` name the
+        compiler gives its custom call."""
+        own = re.compile(re.escape(name) + r"(\.\d+)?")
         return sum(s for k, s in self.op_seconds.items()
-                   if needle in self.op_text[k])
+                   if own.fullmatch(k))
 
     def breakdown(self, n: int = 10) -> dict:
         ops = sorted(self.op_seconds.items(), key=lambda kv: -kv[1])[:n]
@@ -57,13 +61,6 @@ def capture_dir_trace(log_dir: str):
     if not paths:
         raise FileNotFoundError(f"no trace under {log_dir}")
     return ProfileData.from_file(max(paths, key=os.path.getmtime))
-
-
-def _stat_text(ev) -> str:
-    try:
-        return " ".join(str(v) for _, v in ev.stats)
-    except (TypeError, ValueError):
-        return ""
 
 
 def op_key(name: str) -> str:
@@ -123,7 +120,6 @@ def reduce(planes: Iterable) -> Summary:
         return best[2] if best else "no span"
 
     op_ns: Dict[str, int] = {}
-    op_text: Dict[str, str] = {}
     busy_ns, devices, gaps = 0, 0, []
     for p in planes:
         if not p.name.startswith("/device:"):
@@ -139,8 +135,6 @@ def reduce(planes: Iterable) -> Summary:
                     clipped.append((s, e, ev))
         for ev, ns in _self_times(clipped):
             key = op_key(ev.name)
-            if key not in op_text:
-                op_text[key] = f"{ev.name} {_stat_text(ev)}"
             op_ns[key] = op_ns.get(key, 0) + ns
         intervals = [(s, e) for s, e, _ in clipped]
         if not intervals:
@@ -156,4 +150,4 @@ def reduce(planes: Iterable) -> Summary:
     return Summary(window_s=(w1 - w0) * 1e-9,
                    busy_s=busy_ns * 1e-9 / max(devices, 1), devices=devices,
                    op_seconds={k: v * 1e-9 for k, v in op_ns.items()},
-                   op_text=op_text, gaps=gaps)
+                   gaps=gaps)

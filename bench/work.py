@@ -1,74 +1,73 @@
-"""Work counts from shapes: parameters, FLOPs per token, and the live work
+"""Work counts from shapes: the attention of each layer, and the live work
 of one call of the paged attention kernel.
 
-Everything here is arithmetic on a configuration file's sizes and on what
-the requests hold. None of it depends on how the engine pads its tables:
-the kernel's live work is the running requests' resident blocks and the
-rows they feed, whatever grid the kernel walks.
+Everything here is arithmetic on the layers an architecture module gives
+(``bench/arch/<model_type>.py`` ``layers(cfg)``) and on what the requests
+hold. None of it depends on how the engine pads its tables: the kernel's
+live work is the running requests' resident blocks and the rows they feed,
+whatever grid the kernel walks.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Iterable, Tuple
+from typing import Iterable, Optional, Sequence, Tuple
 
 import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
-class Dims:
-    layers: int
-    d_model: int
-    heads: int
+class Layer:
+    """The attention of one layer, as the paged kernel serves it."""
+
+    heads: int                  # query heads
+    kv_heads: int               # heads of the K and V the pool holds
     head_dim: int
-    d_ff: int
-    vocab: int
-    positions: int
-    itemsize: int
-
-    @classmethod
-    def of(cls, cfg: dict) -> "Dims":
-        d = cfg["n_embd"]
-        return cls(layers=cfg["n_layer"], d_model=d, heads=cfg["n_head"],
-                   head_dim=d // cfg["n_head"], d_ff=cfg["n_inner"] or 4 * d,
-                   vocab=cfg["vocab_size"], positions=cfg["n_positions"],
-                   itemsize=np.dtype(_np_dtype(cfg["dtype"])).itemsize)
+    window: Optional[int]       # keys a query sees, itself included;
+    #                             None: every earlier key
+    itemsize: int               # bytes of one element of q, K and V
 
 
-def _np_dtype(name: str):
+def itemsize(dtype: str) -> int:
+    """Bytes of one element of the dtype named ``dtype``."""
     import ml_dtypes
-    return getattr(ml_dtypes, name, None) or np.dtype(name)
+    return np.dtype(getattr(ml_dtypes, dtype, None) or dtype).itemsize
 
 
-def param_count(cfg: dict) -> int:
-    """All parameters: embeddings, per-layer attention and MLP (no
-    biases), LayerNorm gains and biases."""
-    m = Dims.of(cfg)
-    d = m.d_model
-    per_layer = 4 * d * d + 2 * d * m.d_ff + 4 * d
-    return m.vocab * d + m.positions * d + m.layers * per_layer + 2 * d
-
-
-def matmul_flops_per_token(cfg: dict) -> int:
-    """2 FLOPs per weight of every matrix product one token passes:
-    q, k, v, o and the two MLP maps in each layer, and the LM head."""
-    m = Dims.of(cfg)
-    d = m.d_model
-    return 2 * (m.layers * (4 * d * d + 2 * d * m.d_ff) + d * m.vocab)
-
-
-def attention_flops(cfg: dict, pairs: int) -> int:
-    """FLOPs of QK^T and PV over ``pairs`` (query row, visible key) pairs,
-    in all layers: 4 FLOPs per pair per head dimension per head."""
-    m = Dims.of(cfg)
-    return 4 * m.layers * m.heads * m.head_dim * int(pairs)
-
-
-def visible_pairs(rows: int, kv_len: int) -> int:
+def visible_pairs(rows: int, kv_len: int,
+                  window: Optional[int] = None) -> int:
     """Query-key pairs of a chunk of ``rows`` causal rows that end at
-    ``kv_len`` resident tokens (the chunk's own rows included)."""
-    before = kv_len - rows
-    return rows * before + rows * (rows + 1) // 2
+    ``kv_len`` resident tokens (the chunk's own rows included). With a
+    ``window``, a row sees at most ``window`` keys, its own included."""
+    def tri(n):                  # 1 + 2 + ... + n
+        return n * (n + 1) // 2
+
+    lo = kv_len - rows           # the rows see 1 + lo, ..., kv_len keys
+    if window is None or window >= kv_len:
+        return tri(kv_len) - tri(lo)
+    cap = max(lo, window)
+    return tri(cap) - tri(lo) + window * (kv_len - cap)
+
+
+def resident_blocks(rows: int, kv_len: int, block_size: int,
+                    window: Optional[int] = None) -> int:
+    """Blocks of ``kv_len`` resident tokens that hold a key some row of the
+    chunk sees: all of them, or with a ``window`` those from the block of
+    the earliest row's first visible key."""
+    first = 0 if window is None else max(
+        0, (kv_len - rows - window + 1) // block_size)
+    return max(0, math.ceil(kv_len / block_size) - first)
+
+
+def attention_flops(layers: Sequence[Layer],
+                    live: Iterable[Tuple[int, int]]) -> int:
+    """FLOPs of QK^T and PV over the visible pairs of ``live`` ``(rows
+    fed, resident tokens)`` in every layer: 4 FLOPs per pair per head
+    dimension per query head."""
+    live = list(live)
+    return sum(4 * lay.heads * lay.head_dim
+               * sum(visible_pairs(r, k, lay.window) for r, k in live)
+               for lay in layers)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,7 +82,7 @@ class KernelWork:
         return (t_f, "compute") if t_f >= t_b else (t_b, "memory")
 
 
-def kernel_call_work(cfg: dict, geometry: dict,
+def kernel_call_work(layer: Layer, geometry: dict,
                      requests: Iterable[Tuple[int, int]]) -> KernelWork:
     """Live work of one call of the paged kernel (one layer, one step).
 
@@ -91,29 +90,29 @@ def kernel_call_work(cfg: dict, geometry: dict,
     running request that feeds rows. ``geometry`` gives ``block_size`` and
     ``check_stride``; any other key, such as the table width, is ignored.
 
-    Bytes: every resident block's K and V tiles and its four checksum tiles
-    (``check_stride`` rows each), read once; the fed query rows read and
-    the output rows written. FLOPs: QK^T and PV over the visible pairs,
-    and the re-fold of each streamed block's two checksums for K and for V
-    (2 FLOPs per element per checksum).
+    Bytes: every resident block inside the layer's window
+    (:func:`resident_blocks`), its K and V tiles and its four checksum
+    tiles (``check_stride`` rows each) for each KV head, read once; the fed
+    query rows read and the output rows written, for each query head.
+    FLOPs: QK^T and PV over the visible pairs, and the re-fold of each
+    streamed block's two checksums for K and for V (2 FLOPs per element per
+    checksum).
     """
-    m = Dims.of(cfg)
     bs, cs = geometry["block_size"], geometry["check_stride"]
-    hkv = m.heads                   # GPT-2: one KV head per query head
-    tile = hkv * m.head_dim * m.itemsize
+    q_row = layer.heads * layer.head_dim
+    kv_row = layer.kv_heads * layer.head_dim
     flops = nbytes = 0
     for rows, kv_len in requests:
-        blocks = math.ceil(kv_len / bs)
-        nbytes += blocks * (2 * bs + 4 * cs) * tile
-        nbytes += 2 * rows * m.heads * m.head_dim * m.itemsize
-        flops += 4 * m.heads * m.head_dim * visible_pairs(rows, kv_len)
-        flops += blocks * 2 * 2 * 2 * bs * hkv * m.head_dim
+        blocks = resident_blocks(rows, kv_len, bs, layer.window)
+        nbytes += blocks * (2 * bs + 4 * cs) * kv_row * layer.itemsize
+        nbytes += 2 * rows * q_row * layer.itemsize
+        flops += 4 * q_row * visible_pairs(rows, kv_len, layer.window)
+        flops += blocks * 2 * 2 * 2 * bs * kv_row
     return KernelWork(flops=flops, bytes=nbytes)
 
 
-def pool_bytes_per_token(cfg: dict, geometry: dict) -> int:
+def pool_bytes_per_token(layers: Sequence[Layer], geometry: dict) -> int:
     """KV and checksum bytes the pool holds per token, all layers."""
-    m = Dims.of(cfg)
-    per_layer = (2 + 4 * geometry["check_stride"] / geometry["block_size"]) \
-        * m.heads * m.head_dim * m.itemsize
-    return int(m.layers * per_layer)
+    return int(sum(
+        (2 + 4 * geometry["check_stride"] / geometry["block_size"])
+        * lay.kv_heads * lay.head_dim * lay.itemsize for lay in layers))
