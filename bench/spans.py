@@ -22,16 +22,21 @@ an ``XLA Ops`` event's stats are ``device_offset_ps``,
 ``device_duration_ps`` and ``Time Scale Multiplier``, and its name is the
 instruction's HLO text without metadata. The compiled program's text has
 both, under the same instruction names as the trace: :func:`op_scopes`
-maps each name to the innermost program scope of its ``op_name``, and
-:func:`scope_seconds` sums a ``tracing.Summary``'s op self times by scope.
+maps each name to the components of its ``op_name`` path, and
+:func:`scope_seconds` sums a ``tracing.Summary``'s op self times under the
+innermost component of each path that is one of the names its caller
+gives. A program scope is read by the metric file that names it
+(``bench/metrics/<name>.py``): this module knows no scope's name, so a new
+``jax.named_scope`` in the program is measured by a new metric file alone.
 A scope is matched as a whole component of the ``op_name`` path, never as
 a substring of an op's text, which names operands such as
 ``%kv_append.12``.
 
 :func:`summarize` builds the whole of a traced run's reading in one
 place: the :class:`bench.tracing.Summary` of the planes with the spans and
-the scopes beside it (a :class:`Trace`), where ``engine.host_gap_ms`` and
-``model.kv_write_ms`` read them.
+the ``op_name`` paths beside it (a :class:`Trace`), where
+``engine.host_gap_ms`` and the scope readers such as ``model.kv_write_ms``
+read them.
 """
 from __future__ import annotations
 
@@ -45,9 +50,6 @@ PREFIX = "serve."
 STEP = "serve.step"
 OUTSIDE = "outside"
 UNSCOPED = "unscoped"
-# the program's named scopes (models/attention.py, models/transformer.py,
-# serve/paged.py) and the paged kernel's pallas_call name
-SCOPES = ("kv_guard", "kv_append", "efta_paged", "lm_head", "sample")
 
 
 @dataclasses.dataclass
@@ -69,14 +71,6 @@ class Spans:
     @property
     def steps(self) -> List[Span]:
         return [s for s in self.spans if s.name == STEP]
-
-
-def scope_of(op_name: str) -> Optional[str]:
-    """The innermost program scope in an ``op_name`` path, or None."""
-    for part in reversed(op_name.split("/")):
-        if part in SCOPES:
-            return part
-    return None
 
 
 def _window(planes) -> Tuple[float, float]:
@@ -191,25 +185,35 @@ def reduce(planes) -> Spans:
                  device_tail_s=(w1 - last_end) * 1e-9 if devices else 0.0)
 
 
-def op_scopes(hlo_text: str) -> Dict[str, str]:
-    """Instruction name -> innermost program scope of its ``op_name``
-    (``UNSCOPED`` where it has none), for every instruction of a compiled
-    program's HLO text."""
+def op_scopes(hlo_text: str) -> Dict[str, Tuple[str, ...]]:
+    """Instruction name -> the components of its ``op_name`` path (empty
+    where it has none), for every instruction of a compiled program's HLO
+    text."""
     out = {}
     for m in re.finditer(r'^\s*(?:ROOT )?%([\w.\-]+) = (.*)$', hlo_text,
                          re.M):
         op_name = re.search(r'op_name="([^"]*)"', m.group(2))
-        out[m.group(1)] = (op_name and scope_of(op_name.group(1))
-                           or UNSCOPED)
+        out[m.group(1)] = (tuple(op_name.group(1).split("/")) if op_name
+                           else ())
     return out
 
 
-def scope_seconds(summary, scopes: Dict[str, str]) -> Dict[str, float]:
-    """Device self seconds of a ``tracing.Summary``'s ops by program scope
-    (``scopes`` from :func:`op_scopes`; ``UNSCOPED`` for the rest)."""
+def named_scope(path: Tuple[str, ...], names) -> Optional[str]:
+    """The innermost component of ``path`` that is in ``names``, or None."""
+    for part in reversed(path):
+        if part in names:
+            return part
+    return None
+
+
+def scope_seconds(summary, scopes: Dict[str, Tuple[str, ...]],
+                  names) -> Dict[str, float]:
+    """Device self seconds of a ``tracing.Summary``'s ops by the innermost
+    component of each op's path (``scopes`` from :func:`op_scopes`) that
+    is in the caller's ``names``; ``UNSCOPED`` for the rest."""
     out: Dict[str, float] = {}
     for key, s in summary.op_seconds.items():
-        scope = scopes.get(key, UNSCOPED)
+        scope = named_scope(scopes.get(key, ()), names) or UNSCOPED
         out[scope] = out.get(scope, 0.0) + s
     return out
 
@@ -217,15 +221,16 @@ def scope_seconds(summary, scopes: Dict[str, str]) -> Dict[str, float]:
 @dataclasses.dataclass
 class Trace(tracing.Summary):
     """A traced run's reading: the trace's :class:`bench.tracing.Summary`,
-    the window's ``serve.*`` spans, and each instruction's program scope."""
+    the window's ``serve.*`` spans, and each instruction's ``op_name``
+    path."""
     spans: Spans
-    scopes: Dict[str, str]
+    scopes: Dict[str, Tuple[str, ...]]
 
 
 def summarize(planes, hlo_texts) -> Trace:
     """The :class:`Trace` of one window's ``planes`` and the compiled text
     of each step program. The trace keys ops by instruction name alone: a
-    name that several programs use takes the scope of the last of
+    name that several programs use takes the path it has in the last of
     ``hlo_texts``."""
     planes = list(planes)
     return Trace(**vars(tracing.reduce(planes)), spans=reduce(planes),

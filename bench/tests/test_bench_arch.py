@@ -7,12 +7,15 @@ requests, each exactly. Then a second ``model_type`` is added to a copy of
 the benchmark as new files alone (``toy_arch.py``, a test fixture, with its
 configuration, traffic, cell and ``BENCHMARK.json`` entries), and its cell
 runs through ``run.run_cell`` on the CPU: correct, and its float8 control
-not.
+not. A program scope that no harness file names is read the same way:
+by a new metric file (``toy_metric.py``, copied in as
+``bench/metrics/toy.mix_ms.py``) and its entry in ``BENCHMARK.json``.
 """
 import hashlib
 import json
 import shutil
 import time
+import types
 from pathlib import Path
 
 import jax
@@ -20,8 +23,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import catalog, reference, run, weights, work
+from bench import catalog, reference, run, spans, weights, work
 from bench.tests import tiny
+from bench.tests.test_bench_spans import STEP_FN, _host, _op
+from bench.tests.test_bench_tracing import _plane
 
 GPT2 = catalog.load_arch("gpt2")
 SEED = 2 ** 33 + 7
@@ -127,6 +132,9 @@ TOY_BENCH = {
     "workloads": [{"name": "toy.short", "config": "toy",
                    "traffic": "toy_short", "chips": 1,
                    "why": "a test fixture"}],
+    "per_layer": [{"name": "toy.mix_ms", "unit": "ms", "better": "lower",
+                   "source": "device_trace", "layer": "toy mixer",
+                   "moves": "itl_p95_ms", "workloads": ["toy.short"]}],
 }
 CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
 PEAKS = {"flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
@@ -150,6 +158,8 @@ def toy_root(tmp_path_factory):
         "bench/configs/toy.json": json.dumps(TOY_CONFIG),
         "bench/traffic/toy_short.json": json.dumps(tiny.TRAFFIC),
         "bench/cells/toy.short.json": json.dumps(TOY_CELL),
+        "bench/metrics/toy.mix_ms.py":
+            (catalog.BENCH_DIR / "tests" / "toy_metric.py").read_text(),
     }
     for rel, text in new.items():
         assert not (root / rel).exists()
@@ -211,3 +221,59 @@ def test_a_new_model_types_layers_count_kv_heads_and_windows(toy_root):
     fold = 2 * 2 * 2 * 16 * 2 * 16
     assert a.flops == 4 * 4 * 16 * 70 + 5 * fold
     assert b.flops == 4 * 4 * 16 * 16 + 2 * fold
+
+
+# -- a new program scope, as a new metric file ------------------------------
+
+def _toy_step():
+    """A traced window of two engine steps whose device ops sit under the
+    toy mixer's scopes, and the compiled program's text that names them."""
+    def meta(path):
+        return f'metadata={{op_name="{STEP_FN}/{path}" stack_frame_id=1}}'
+    ops = [
+        # its text names the operand %toy_mix.3; its time is toy_mix's
+        ("%fusion.7 = f32[8,64] fusion(f32[8,64] %x, f32[64,128] "
+         "%toy_mix.3), kind=kOutput", "toy_mix/dot_general", 105, 60),
+        # toy_gate is opened inside toy_mix: the innermost one counts
+        ("%reduce.2 = f32[8] reduce(f32[8,64] %y)",
+         "toy_mix/toy_gate/reduce_max", 300, 30),
+        # a scope of another reader, and a name toy_mix only begins
+        ("%fusion.12 = f32[8] fusion(f32[8] %a), kind=kLoop",
+         "kv_append/scatter", 525, 20),
+        ("%fusion.9 = f32[8] fusion(f32[8] %b), kind=kLoop",
+         "toy_mixer/dot_general", 560, 40),
+        ("%copy.5 = f32[8] copy(f32[8] %d)", None, 850, 20)]
+    device = _plane("/device:TPU:0", **{"XLA Ops": [
+        _op(text.split(", kind=")[0], start, dur)
+        for text, _, start, dur in ops]})
+    hlo = "\n".join(f"  {text}, {meta(path)}" if path else f"  {text}"
+                     for text, path, _, _ in ops)
+    hlo += f"\n  %toy_mix.3 = f32[64,128] parameter(3), {meta('toy_mix/w')}"
+    return [_host(), device], hlo
+
+
+def test_a_new_program_scope_is_read_by_a_new_metric_file(toy_root):
+    # no harness file of the copy names the scope; every file the
+    # benchmark has is in the copy as it is here
+    for path in catalog.BENCH_DIR.rglob("*"):
+        if path.is_file() and "__pycache__" not in path.parts:
+            rel = path.relative_to(catalog.ROOT)
+            assert (toy_root / rel).read_bytes() == path.read_bytes(), rel
+    harness = [p for p in (toy_root / "bench").rglob("*.py")
+               if p.name != "toy.mix_ms.py" and "tests" not in p.parts]
+    assert harness and not any("toy_mix" in p.read_text() for p in harness)
+    # the cell finds the reader by its name in BENCHMARK.json
+    cell = catalog.load_cell("toy.short", toy_root)
+    (metric,) = [m for m in cell.per_layer if m.name == "toy.mix_ms"]
+    planes, hlo = _toy_step()
+    trace = spans.summarize(planes, [hlo])
+    got = metric.read(types.SimpleNamespace(trace=trace,
+                                            steps=[object()] * 2))
+    # 60 us under toy_mix and 30 under toy_gate, over two steps
+    assert got["toy_mix_ms"] == pytest.approx(0.03)
+    assert got["toy_gate_ms"] == pytest.approx(0.015)
+    assert got["value"] == pytest.approx(0.045)
+    # a program without the scopes reads nothing
+    bare = spans.summarize(planes, [hlo.replace("toy_", "other_")])
+    assert metric.read(types.SimpleNamespace(trace=bare,
+                                             steps=[object()] * 2)) is None

@@ -10,6 +10,8 @@ from bench import catalog, spans, tracing
 from bench.tests.test_bench_tracing import _ev, _plane
 
 STEP_FN = "jit(_step_fused_fn)/while/body/closed_call/checkpoint"
+# the scopes model.kv_write_ms names
+NAMES = ("kv_guard", "kv_append", "efta_paged", "lm_head", "sample")
 KERNEL = ('%efta_paged.1 = (f32[8,12,32,64]) custom-call(f32[8,12,32,64] '
           '%q, /*index=1*/f32[8] %kv_append.12), '
           'custom_call_target="tpu_custom_call"')
@@ -101,14 +103,33 @@ def test_nested_wait_keeps_its_own_share():
 
 def test_ops_are_attributed_by_the_op_name_of_their_instruction():
     scopes = spans.op_scopes(_hlo())
-    assert scopes["efta_paged.1"] == "efta_paged"
-    assert scopes["copy.5"] == spans.UNSCOPED
+    assert scopes["efta_paged.1"] == (*STEP_FN.split("/"), "efta_paged",
+                                      "pallas_call")
+    assert scopes["copy.5"] == ()
     # the kernel's text names the operand %kv_append.12 (after a /*index*/
     # comment); its time stays the kernel's
-    by_scope = spans.scope_seconds(tracing.reduce(_trace()), scopes)
+    by_scope = spans.scope_seconds(tracing.reduce(_trace()), scopes, NAMES)
     assert {k: round(v * 1e6) for k, v in by_scope.items()} == {
         "efta_paged": 400, "kv_append": 40, "kv_guard": 20, "lm_head": 50,
         "unscoped": 20}
+
+
+@pytest.mark.parametrize("names,want", [
+    # a name the caller leaves out counts for nothing
+    (("kv_append",), {"kv_append": 40, "unscoped": 490}),
+    # an enclosing component takes every op under it; the innermost named
+    # component wins
+    (("checkpoint",), {"checkpoint": 510, "unscoped": 20}),
+    (("checkpoint", "lm_head"), {"checkpoint": 460, "lm_head": 50,
+                                 "unscoped": 20}),
+    # a component is matched whole, never as a substring
+    (("kv", "append", "efta"), {"unscoped": 530}),
+    ((), {"unscoped": 530}),
+])
+def test_scope_seconds_reads_the_names_its_caller_gives(names, want):
+    by_scope = spans.scope_seconds(tracing.reduce(_trace()),
+                                   spans.op_scopes(_hlo()), names)
+    assert {k: round(v * 1e6) for k, v in by_scope.items()} == want
 
 
 def test_summary_reads_the_same_with_and_without_program_spans():
@@ -182,8 +203,8 @@ def test_traced_run_gives_the_summary_spans_and_scopes():
     widths = {s.args["width"] for s in summary.spans.steps
               if "width" in s.args}
     assert widths and widths <= {1, tiny.CONFIG["engine"]["chunk_size"]}
-    assert {"kv_guard", "kv_append", "lm_head", "sample"} <= set(
-        summary.scopes.values())
+    assert {"kv_guard", "kv_append", "lm_head", "sample"} <= {
+        spans.named_scope(path, NAMES) for path in summary.scopes.values()}
     names = {m.name for m in tiny.cell().per_layer}
     assert {"engine.host_gap_ms", "model.kv_write_ms"} <= names
     assert not names & {"engine.host_gap_ms", "model.kv_write_ms"} & set(
